@@ -102,7 +102,11 @@ def _baseline_tick_rate() -> float:
     return 1e3 / bench_tick(16, 16384, "equilibria", n_ticks=20)["tick_ms"]
 
 
-def _rollout(H: int, ticks: int, chunk: int, warmup: bool = True):
+def rollout(H: int, ticks: int, chunk: int, warmup: bool = True,
+            shard: bool = True):
+    """The sweep's mixed fleet of H hosts over ``ticks`` through
+    ``fleet_rollout`` (detectors and attribution on). Returns
+    (cfg, summary, lifecycle events)."""
     from repro.core.churn import churn_events
     from repro.obs.fleet import fleet_rollout
     period = min(SMOKE_CHUNK, ticks)
@@ -111,13 +115,14 @@ def _rollout(H: int, ticks: int, chunk: int, warmup: bool = True):
     host_arch = np.arange(H) % A
     cfg = _config()
     summary = fleet_rollout(cfg, want, rates, ticks, host_arch=host_arch,
-                            chunk=chunk, k_max=16, warmup=warmup)
+                            chunk=chunk, k_max=16, warmup=warmup,
+                            shard=shard)
     per_arch = [sum(churn_events(want[a])) for a in range(A)]
     events = sum(per_arch[a] for a in host_arch)
     return cfg, summary, events
 
 
-def _conserved(cfg, summary) -> bool:
+def conserved(cfg, summary) -> bool:
     """fast + slow + free == L on every host of the final fleet state."""
     from repro.core.state import TIER_FAST, TIER_SLOW
     tier = np.asarray(summary.final_state.tier)
@@ -138,8 +143,7 @@ def _fork_for_devices() -> None:
     if "xla_force_host_platform_device_count" in flags:
         return
     # largest power of two <= min(cores, 8): always divides the 128-host
-    # smoke fleet, so the pmap-sharded path really runs (fleet_rollout only
-    # shards when H % devices == 0)
+    # smoke fleet (fleet_rollout refuses a host count that does not divide)
     n = 1 << (min(os.cpu_count() or 1, 8).bit_length() - 1)
     if n < 2:
         return
@@ -157,16 +161,16 @@ def smoke() -> int:
     import jax
     t0 = time.perf_counter()
     base_rate = _baseline_tick_rate()
-    cfg, summary, events = _rollout(SMOKE_HOSTS, SMOKE_TICKS, SMOKE_CHUNK)
+    cfg, summary, events = rollout(SMOKE_HOSTS, SMOKE_TICKS, SMOKE_CHUNK)
     elapsed = time.perf_counter() - t0
     L = cfg.n_fast_pages + cfg.n_slow_pages
     rate = summary.host_ticks_per_s
-    conserved = _conserved(cfg, summary)
+    ok_conserved = conserved(cfg, summary)
     # streaming pathology telemetry rode along in the fleet carry: flag
     # counters at any horizon with O(H * T) memory, never [ticks, ...]
     detected = summary.detector is not None
     flags = summary.pathology_flag_ticks() if detected else None
-    ok = (rate >= base_rate and conserved and elapsed < SMOKE_BUDGET_S
+    ok = (rate >= base_rate and ok_conserved and elapsed < SMOKE_BUDGET_S
           and events > 0 and detected)
     print(f"fleet smoke: {SMOKE_HOSTS} mixed hosts (static+churn, "
           f"{events} lifecycle events) x {SMOKE_TICKS} ticks, "
@@ -183,7 +187,7 @@ def smoke() -> int:
     print(f"  rollout {summary.elapsed_s:.1f}s steady -> "
           f"{rate:,.0f} host-ticks/s "
           f"({rate * L:,.0f} page-ticks/s), baseline {base_rate:,.1f} "
-          f"ticks/s; conserved={conserved} "
+          f"ticks/s; conserved={ok_conserved} "
           f"total={elapsed:.1f}s budget={SMOKE_BUDGET_S:.0f}s "
           f"-> {'OK' if ok else 'FAIL'}")
     if not summary.sharded:
@@ -201,7 +205,7 @@ def main() -> int:
     sweep = []
     for H in HOSTS:
         for ticks in HORIZONS:
-            cfg, summary, events = _rollout(H, ticks, SMOKE_CHUNK)
+            cfg, summary, events = rollout(H, ticks, SMOKE_CHUNK)
             L = cfg.n_fast_pages + cfg.n_slow_pages
             r = {"hosts": H, "ticks": ticks, "chunk": summary.chunk,
                  "sharded": summary.sharded,
@@ -211,7 +215,7 @@ def main() -> int:
                  "page_ticks_per_s": round(summary.host_ticks_per_s * L, 1),
                  "fleet_tick_ms": round(
                      summary.elapsed_s / ticks * 1e3, 3),
-                 "conserved": _conserved(cfg, summary)}
+                 "conserved": conserved(cfg, summary)}
             sweep.append(r)
             print(f"H={H:4d} ticks={ticks:6d} sharded={r['sharded']!s:5s} "
                   f"tick={r['fleet_tick_ms']:7.2f}ms "

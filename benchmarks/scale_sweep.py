@@ -26,18 +26,26 @@ SMOKE_BUDGET_S = 120.0          # compile + 50 ticks, T=16, L=16k (CI gate)
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "scale.json")
 
 
-def _build(T: int, L: int, mode: str, impl: str):
-    import jax.numpy as jnp
+def scale_point(T: int, L: int):
+    """The sweep's host: T equal contiguous tenants over L pages, a fast
+    tier of L/4, protections at half and bounds at twice the fair share.
+    Returns (cfg, owner [L])."""
     from repro.configs.base import TieringConfig
-    from repro.core.engine import make_tick
-    from repro.core.state import init_state
-
     share = L // (4 * T)        # fast tier is L/4 pages; share = fair split
     cfg = TieringConfig(
         n_tenants=T, n_fast_pages=L // 4, n_slow_pages=L,
         lower_protection=(max(share // 2, 1),) * T,
         upper_bound=(2 * share,) * T)   # exercises Eq.1/Eq.2 + sync path
     owner = np.repeat(np.arange(T, dtype=np.int32), L // T)
+    return cfg, owner
+
+
+def _build(T: int, L: int, mode: str, impl: str):
+    import jax.numpy as jnp
+    from repro.core.engine import make_tick
+    from repro.core.state import init_state
+
+    cfg, owner = scale_point(T, L)
     tick = make_tick(cfg, owner, mode, k_max=256, impl=impl)
     state = init_state(cfg, L)
     rng = np.random.default_rng(0)

@@ -39,13 +39,11 @@ def bad_purity():
 # ---------------------------------------------------------------- dtype ----
 def bad_dtype():
     """A float64 leak: traced under an enable_x64 escape hatch."""
-    from jax.experimental import enable_x64
-
     def f(x):
         y = jnp.asarray(x, jnp.float64)      # the leak
         return (y * 2.0).sum()
 
-    with enable_x64():
+    with jax.enable_x64(True):
         return jax.make_jaxpr(f)(np.zeros((4,), np.float32))
 
 

@@ -28,7 +28,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
-from jax import core as jax_core
+from jax.extend.core import Literal
 
 from repro.analysis.walk import ClosedJaxpr, subjaxprs
 
@@ -179,7 +179,7 @@ class IntervalEvaluator:
         return [self._read(env, v) for v in jaxpr.outvars]
 
     def _read(self, env, v) -> Interval:
-        if isinstance(v, jax_core.Literal):
+        if isinstance(v, Literal):
             return value_interval(v.val)
         if v not in env:
             # DropVar or untracked: fall back to dtype range

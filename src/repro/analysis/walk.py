@@ -11,15 +11,7 @@ from __future__ import annotations
 
 from typing import Dict, Iterator, List, Tuple
 
-from jax import core as jax_core
-
-try:  # jax >= 0.4.24 moved ClosedJaxpr around; resolve defensively
-    ClosedJaxpr = jax_core.ClosedJaxpr
-    Jaxpr = jax_core.Jaxpr
-except AttributeError:  # pragma: no cover
-    from jax.extend import core as jax_core  # type: ignore
-    ClosedJaxpr = jax_core.ClosedJaxpr
-    Jaxpr = jax_core.Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def subjaxprs(eqn) -> List[Tuple[str, object]]:

@@ -297,6 +297,19 @@ class Strategy(NamedTuple):
     move: Optional[Callable[..., tuple]] = None
 
 
+# engine impl -> kernel-wrapper impl (kernels/select, kernels/migrate):
+# "pallas" lowers through Mosaic, "pallas_interpret" runs the same kernel
+# bodies on the Pallas interpreter, "pallas_ref" compiles their jnp oracles
+KERNEL_IMPLS = {"pallas": "pallas", "pallas_interpret": "pallas_interpret",
+                "pallas_ref": "ref"}
+
+
+def _check_impl(impl: str, allowed: tuple) -> None:
+    if impl not in allowed:
+        raise ValueError(f"unknown selection impl {impl!r}; expected one of "
+                         f"{sorted(set(allowed) | set(KERNEL_IMPLS))}")
+
+
 def static_strategy(owner: np.ndarray, n_tenants: int, k_max: int,
                     impl: str = "batched") -> Strategy:
     """Strategy for a trace-constant owner vector. Picks the fastest
@@ -311,8 +324,9 @@ def static_strategy(owner: np.ndarray, n_tenants: int, k_max: int,
     T = n_tenants
     if impl == "jnp":
         impl = "batched"
-    if impl in ("pallas", "pallas_interpret", "pallas_ref"):
+    if impl in KERNEL_IMPLS:
         return pallas_static_strategy(owner, n_tenants, k_max, impl)
+    _check_impl(impl, ("batched", "unrolled"))
     owner_j = jnp.asarray(owner, jnp.int32)
     if impl == "unrolled":
         owner_oh = jnp.asarray(
@@ -365,8 +379,9 @@ def dynamic_strategy(n_tenants: int, k_max: int,
     the tiled segmented top-k kernel (see ``pallas_dynamic_strategy``)."""
     if impl == "jnp":
         impl = "batched"
-    if impl in ("pallas", "pallas_interpret", "pallas_ref"):
+    if impl in KERNEL_IMPLS:
         return pallas_dynamic_strategy(n_tenants, k_max, impl)
+    _check_impl(impl, ("batched",))
     T = n_tenants
 
     def by_tenant(x: jax.Array, owner: jax.Array) -> jax.Array:
@@ -442,8 +457,8 @@ def pallas_static_strategy(owner: np.ndarray, n_tenants: int, k_max: int,
     bit-identical."""
     from repro.kernels.migrate import ops as KMIG
     from repro.kernels.select import ops as KSEL
-    kimpl = {"pallas": "pallas",
-             "pallas_ref": "ref"}.get(impl, "pallas_interpret")
+    _check_impl(impl, tuple(KERNEL_IMPLS))
+    kimpl = KERNEL_IMPLS[impl]
     T = n_tenants
     owner_np = np.asarray(owner)
     owner_j = jnp.asarray(owner_np, jnp.int32)
@@ -523,8 +538,8 @@ def pallas_dynamic_strategy(n_tenants: int, k_max: int,
     is known), so the perf target remains the static contiguous strategy;
     reductions stay on the pool-sentinel-tolerant jnp scatters."""
     from repro.kernels.select import ops as KSEL
-    kimpl = {"pallas": "pallas",
-             "pallas_ref": "ref"}.get(impl, "pallas_interpret")
+    _check_impl(impl, tuple(KERNEL_IMPLS))
+    kimpl = KERNEL_IMPLS[impl]
     T = n_tenants
 
     def by_tenant(x: jax.Array, owner: jax.Array) -> jax.Array:

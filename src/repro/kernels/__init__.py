@@ -1,16 +1,17 @@
-# Custom Pallas kernels for the compute hot-spots the paper optimizes
-# (tiered attention, page migration, flash attention, SSD scan), plus
-# shared TPU-lowering compatibility shims.
-"""Kernel package utilities shared by all Pallas kernels."""
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed ``TPUCompilerParams`` to ``CompilerParams`` (jax >= 0.5);
-# resolve whichever this jax exposes so kernels build on both.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None)
-if _COMPILER_PARAMS_CLS is None:
-    _COMPILER_PARAMS_CLS = pltpu.TPUCompilerParams
+"""Custom Pallas TPU kernels for the tiering hot path (selection, page
+moves) and the serving path (tiered attention, flash attention, SSD scan)."""
 
 
-def tpu_compiler_params(dimension_semantics, **kw):
-    """Version-portable ``compiler_params`` for ``pl.pallas_call``."""
-    return _COMPILER_PARAMS_CLS(dimension_semantics=dimension_semantics, **kw)
+def pad_up(n: int, m: int) -> int:
+    """``n`` rounded up to a multiple of ``m`` (the kernels' tile sizes)."""
+    return -(-n // m) * m
+
+
+def interpret_mode(impl: str) -> bool:
+    """``interpret=`` flag of a kernel wrapper's non-"ref" ``impl``:
+    "pallas" lowers through Mosaic, "pallas_interpret" runs the same kernel
+    body on the Pallas interpreter. Any other name is an error."""
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown kernel impl {impl!r}; expected 'ref', "
+                         "'pallas' or 'pallas_interpret'")
+    return impl == "pallas_interpret"

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -121,6 +119,7 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -15,12 +15,13 @@ sizes the per-program dispatch overhead dominated the copy itself.
 pass applies the promotion/demotion scatter to the [L] tier vector AND
 appends the packed migration-ring events, replacing a drop-mode scatter
 plus the five-column ring build/scatter of ``obs/trace.ring_record``. The
-vector phase computes the ring slot of every taken lane (log-shift prefix
-sum, newest-C-wins window, modular head offset — bit-identical to the jnp
-ring math); the scalar phase walks the compact [N = T·k] lane stream and
-commits both stores. ``tier`` and ``ring_data`` are input/output-aliased:
-the commit is in-place, the way a real migration engine retires a move
-queue.
+compact [N = T·k] lane stream walks through SMEM one block per grid step,
+and each taken lane commits in lane order: a masked store into the
+[8, 128] tier tile that holds its page, and one into the ring tile of the
+next slot. Writing slots in lane order keeps the newest event of a slot
+written twice, which is exactly the jnp ring's newest-C-wins window.
+``tier`` and ``ring_data`` are input/output-aliased: the commit is
+in-place, the way a real migration engine retires a move queue.
 """
 from __future__ import annotations
 
@@ -30,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import tpu_compiler_params
 
 
 def _mig_kernel(src_idx_ref, dst_idx_ref, sel_ref, src_ref, dst_in_ref,
@@ -77,81 +76,88 @@ def migrate_pages_tpu(src_pool, dst_pool, src_idx, dst_idx, sel, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(dst_pool.shape, dst_pool.dtype),
         input_output_aliases={4: 0},   # dst_pool (3 scalars + src = idx 4) -> out
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.maximum(src_idx, 0), jnp.maximum(dst_idx, 0),
       sel.astype(jnp.int32), src_pool, dst_pool)
 
 
 # ------------------------------------------------------- commit_moves ------
-def _row_prefix(x):
-    """Inclusive prefix sum along axis 1 (log-shift adds, int32)."""
-    N = x.shape[1]
-    inc = x
-    off = 1
-    while off < N:
-        shifted = jnp.concatenate(
-            [jnp.zeros((x.shape[0], off), jnp.int32), inc[:, :-off]], axis=1)
-        inc = inc + shifted
-        off *= 2
-    return inc
+def _moves_kernel(pages_ref, take_ref, ten_ref, hot_ref, scal_ref,
+                  tier_in_ref, ring_in_ref, tier_ref, ring_ref, head_ref,
+                  cnt_ref, *, direction: int, to_tier: int, n_slots: int):
+    """One grid step commits one SMEM block of the lane stream. The tier
+    vector is a [L/1024, 8, 128] view and the ring a [C/8, 8, 5] view, so
+    each store is a masked update of one tile picked by a leading-axis
+    index. Ring slots follow the lane order: a slot written twice keeps
+    its newest event, which is exactly the newest-C-wins window."""
+    step = pl.program_id(0)
+    t, head = scal_ref[0], scal_ref[1]
+
+    @pl.when(step == 0)
+    def _init():
+        tier_ref[...] = tier_in_ref[...]
+        ring_ref[...] = ring_in_ref[...]
+        cnt_ref[0] = 0
+
+    tile = tier_ref.shape[1:]
+    sub = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+    rsub = jax.lax.broadcasted_iota(jnp.int32, ring_ref.shape[1:], 0)
+    rcol = jax.lax.broadcasted_iota(jnp.int32, ring_ref.shape[1:], 1)
+
+    def commit(j, cnt):
+        taken = take_ref[j] != 0
+
+        @pl.when(taken)
+        def _store():
+            page = pages_ref[j]
+            blk, off = page // 1024, page % 1024
+            tier_ref[blk] = jnp.where((sub == off // 128) & (lane == off % 128),
+                                      to_tier, tier_ref[blk])
+            slot = (head + cnt) % n_slots
+            row = jnp.where(rcol == 0, t, jnp.where(
+                rcol == 1, ten_ref[j], jnp.where(
+                    rcol == 2, page, jnp.where(rcol == 3, direction,
+                                               hot_ref[j]))))
+            ring_ref[slot // 8] = jnp.where(rsub == slot % 8, row,
+                                            ring_ref[slot // 8])
+        return cnt + taken.astype(jnp.int32)
+
+    cnt = jax.lax.fori_loop(0, pages_ref.shape[0], commit, cnt_ref[0])
+    cnt_ref[0] = cnt
+    head_ref[0] = head + cnt
 
 
-def _moves_kernel(pages_ref, take_ref, ten_ref, hot_ref, t_ref, head_ref,
-                  tier_in_ref, ring_in_ref, tier_ref, ring_ref, head_out_ref,
-                  idx_ref, *, direction: int, to_tier: int):
-    C = ring_ref.shape[0]
-    N = pages_ref.shape[1]
-    take = take_ref[...]                               # [1, N] i32
-    incl = _row_prefix(take)
-    offs = incl - 1                                    # slot among selected
-    total = incl[0, -1]
-    head = head_ref[0, 0]
-    keep = (take != 0) & (offs >= total - C)           # newest C events win
-    idx_ref[...] = jnp.where(keep, (head + offs) % C, C)   # C = OOB -> skip
-    tier_ref[...] = tier_in_ref[...]
-    ring_ref[...] = ring_in_ref[...]
-    head_out_ref[0, 0] = head + total
-
-    def commit(j, _):
-        @pl.when(take_ref[0, j] != 0)
-        def _tier():
-            tier_ref[0, pages_ref[0, j]] = to_tier
-
-        ii = idx_ref[0, j]
-
-        @pl.when(ii < C)
-        def _ring():
-            ring_ref[ii, 0] = t_ref[0, 0]
-            ring_ref[ii, 1] = ten_ref[0, j]
-            ring_ref[ii, 2] = pages_ref[0, j]
-            ring_ref[ii, 3] = direction
-            ring_ref[ii, 4] = hot_ref[0, j]
-        return 0
-
-    jax.lax.fori_loop(0, N, commit, 0)
-
-
-def commit_moves_tpu(tier, ring_data, head, pages, take, tenants, hot_bits,
-                     t, *, direction: int, to_tier: int,
-                     interpret: bool = False):
-    """tier [1, L] i32; ring_data [C, 5] i32; head/t [1, 1] i32;
-    pages/take/tenants/hot_bits [1, N] i32. Whole-array refs, no grid:
-    the move stream is the compact [T·k] candidate lane space, small enough
-    to sit in VMEM next to the tier vector."""
-    L = tier.shape[1]
-    C = ring_data.shape[0]
-    N = pages.shape[1]
+def commit_moves_tpu(tier, ring_data, scal, pages, take, tenants, hot_bits,
+                     *, n_slots: int, direction: int, to_tier: int,
+                     lanes_per_step: int = 1024, interpret: bool = False):
+    """tier [L/1024, 8, 128] i32; ring_data [C/8, 8, 5] i32 (``n_slots``
+    real rows); scal [2] i32 = (tick, head); pages/take/tenants/hot_bits
+    [N] i32 with N a multiple of ``lanes_per_step``. The lane stream walks
+    through SMEM one block per grid step while the tier and ring views stay
+    resident in VMEM. Returns (tier', ring_data', head' [1])."""
+    N = pages.shape[0]
+    nb = lanes_per_step
+    lanes = pl.BlockSpec((nb,), lambda i: (i,), memory_space=pltpu.SMEM)
+    whole = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tier_spec = pl.BlockSpec(tier.shape, lambda i: (0, 0, 0))
+    ring_spec = pl.BlockSpec(ring_data.shape, lambda i: (0, 0, 0))
     return pl.pallas_call(
         functools.partial(_moves_kernel, direction=direction,
-                          to_tier=to_tier),
+                          to_tier=to_tier, n_slots=n_slots),
+        grid=(N // nb,),
+        in_specs=[lanes, lanes, lanes, lanes, whole, tier_spec, ring_spec],
+        out_specs=[tier_spec, ring_spec, whole],
         out_shape=[
-            jax.ShapeDtypeStruct((1, L), jnp.int32),
-            jax.ShapeDtypeStruct((C, 5), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            jax.ShapeDtypeStruct(tier.shape, jnp.int32),
+            jax.ShapeDtypeStruct(ring_data.shape, jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
-        input_output_aliases={6: 0, 7: 1},   # tier, ring_data -> in place
-        scratch_shapes=[pltpu.VMEM((1, N), jnp.int32)],
-        compiler_params=tpu_compiler_params(()),
+        input_output_aliases={5: 0, 6: 1},   # tier, ring_data -> in place
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pages, take, tenants, hot_bits, t, head, tier, ring_data)
+    )(pages, take, tenants, hot_bits, scal, tier, ring_data)

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode, pad_up
 from repro.kernels.migrate.kernel import commit_moves_tpu, migrate_pages_tpu
 from repro.kernels.migrate.ref import commit_moves_ref, migrate_pages_ref
 
@@ -18,7 +19,7 @@ def migrate_pages(src_pool, dst_pool, src_idx, dst_idx, sel, *,
         return migrate_pages_ref(src_pool, dst_pool, src_idx, dst_idx, sel)
     return migrate_pages_tpu(src_pool, dst_pool, src_idx, dst_idx, sel,
                              page_block=page_block,
-                             interpret=(impl == "pallas_interpret"))
+                             interpret=interpret_mode(impl))
 
 
 @functools.partial(jax.jit, static_argnames=("direction", "to_tier", "impl"))
@@ -36,22 +37,18 @@ def commit_moves(tier, ring_data, head, pages, take, tenants, hot, t, *,
         return commit_moves_ref(tier, ring_data, head, pages, take, tenants,
                                 hot_bits, t, direction=direction,
                                 to_tier=to_tier)
-    # lane-pad the move stream to a multiple of 128: untaken pad lanes are
-    # commit no-ops, and the fixed width keeps the kernel's prefix-scan
-    # depth (and so the tick jaxpr) constant across stream sizes
-    n = pages.shape[0]
-    pad = -n % 128
-    if pad:
-        pages = jnp.pad(pages, (0, pad))
-        take = jnp.pad(take, (0, pad))
-        tenants = jnp.pad(tenants, (0, pad))
-        hot_bits = jnp.pad(hot_bits, (0, pad))
-    tier2, data2, head2 = commit_moves_tpu(
-        tier[None].astype(jnp.int32), ring_data,
-        head.astype(jnp.int32).reshape(1, 1),
-        pages[None].astype(jnp.int32), take[None].astype(jnp.int32),
-        tenants[None].astype(jnp.int32), hot_bits[None],
-        t.astype(jnp.int32).reshape(1, 1),
-        direction=direction, to_tier=to_tier,
-        interpret=(impl == "pallas_interpret"))
-    return tier2[0], data2, head2[0, 0]
+    # lane-pad the move stream to a multiple of the kernel's SMEM block:
+    # untaken pad lanes are commit no-ops, and the fixed block keeps the
+    # tick jaxpr constant across stream sizes
+    n, L, C = pages.shape[0], tier.shape[0], ring_data.shape[0]
+    nb = min(pad_up(n, 128), 1024)
+    pad = -n % nb
+    lanes = [jnp.pad(a.astype(jnp.int32), (0, pad))
+             for a in (pages, take, tenants, hot_bits)]
+    tier_v = jnp.pad(tier.astype(jnp.int32), (0, -L % 1024)).reshape(-1, 8, 128)
+    ring_v = jnp.pad(ring_data, ((0, -C % 8), (0, 0))).reshape(-1, 8, 5)
+    scal = jnp.stack([t.astype(jnp.int32), head.astype(jnp.int32)])
+    tier2, ring2, head2 = commit_moves_tpu(
+        tier_v, ring_v, scal, *lanes, n_slots=C, direction=direction,
+        to_tier=to_tier, lanes_per_step=nb, interpret=interpret_mode(impl))
+    return (tier2.reshape(-1)[:L], ring2.reshape(-1, 5)[:C], head2[0])

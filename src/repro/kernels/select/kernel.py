@@ -1,9 +1,10 @@
 """Selection-core Pallas TPU kernels: tiled segmented top-k + reductions.
 
-Both kernels tile the [T, S] tenant-row space over row blocks of
-``block_rows`` (grid = T/block_rows programs, each owning a [block_rows, S]
-VMEM-resident tile), so VMEM pressure is bounded by the widest tenant row,
-not by L, and the grid is embarrassingly parallel across tenants.
+The kernels tile the [T, S] tenant-row space over row blocks of
+``block_rows``, parallel across tenants. ``seg_reduce``/``seg_sums`` hold
+one [block_rows, S] tile; ``seg_topk`` also walks the columns in
+[block_rows, block_cols] tiles with a running top-k, so its VMEM use is
+bounded by the tile, not by S (the dynamic strategy's rows are S = L).
 
 ``seg_topk`` fuses the per-tenant masking, scoring and quota-bounded
 selection that the jnp path spreads across a gather, a masked ``top_k``
@@ -30,69 +31,90 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 # ------------------------------------------------------------- seg_topk ----
 def _seg_topk_kernel(score_ref, valid_ref, quota_ref, cols_ref, take_ref,
-                     cnt_ref, *, k: int):
-    s = jnp.where(valid_ref[...] != 0, score_ref[...], -jnp.inf)  # [Bt, S]
-    Bt, S = s.shape
+                     cnt_ref, val_ref, *, k: int, S: int):
+    """One [Bt, Sc] column block of a row block. The row's running top-k
+    (values in ``val_ref``, columns in ``cols_ref``) is merged with the
+    block by the same max-extraction; earlier blocks hold lower columns,
+    so the (score desc, column asc) order is the whole row's."""
+    j = pl.program_id(1)
+    s = jnp.where(valid_ref[...] != 0, score_ref[...], -jnp.inf)  # [Bt, Sc]
+    Bt, Sc = s.shape
     q = quota_ref[...][:, 0]                                      # [Bt] i32
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (Bt, S), 1)
+    col = j * Sc + jax.lax.broadcasted_iota(jnp.int32, (Bt, Sc), 1)
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (Bt, k), 1)
-    # Quota-bounded round count: rows that exhaust their quota (or run out
-    # of eligible columns) keep looping but stop committing lanes.
+
+    @pl.when(j == 0)
+    def _init():
+        val_ref[...] = jnp.full((Bt, k), -jnp.inf, jnp.float32)
+        cols_ref[...] = jnp.full((Bt, k), S, jnp.int32)
+
+    # Quota-bounded round count: lanes past a row's own quota are dropped
+    # at the end, so every row keeps the block's best ``rounds``.
     rounds = jnp.minimum(jnp.max(jnp.maximum(q, 0)), k)
 
-    def round_(j, carry):
-        s, cols, take = carry
-        m = jnp.max(s, axis=1)                       # row max  [Bt]
-        hit = s == m[:, None]
-        c = jnp.min(jnp.where(hit, col_iota, S), axis=1)   # lowest max col
-        ok = (m > -jnp.inf) & (j < q)
-        lane = lane_iota == j
-        commit = lane & ok[:, None]
-        cols = jnp.where(commit, c[:, None], cols)
-        take = jnp.where(commit, 1, take)
-        s = jnp.where(col_iota == c[:, None], -jnp.inf, s)  # consume winner
-        return s, cols, take
+    def round_(r, carry):
+        s, cv, cc, nv, nc = carry
+        m = jnp.maximum(jnp.max(s, axis=1), jnp.max(cv, axis=1))   # [Bt]
+        c = jnp.minimum(                                   # lowest max col
+            jnp.min(jnp.where(cv == m[:, None], cc, S), axis=1),
+            jnp.min(jnp.where(s == m[:, None], col, S), axis=1))
+        commit = (lane_iota == r) & (m > -jnp.inf)[:, None]
+        nv = jnp.where(commit, m[:, None], nv)
+        nc = jnp.where(commit, c[:, None], nc)
+        s = jnp.where(col == c[:, None], -jnp.inf, s)       # consume winner
+        cv = jnp.where(cc == c[:, None], -jnp.inf, cv)
+        return s, cv, cc, nv, nc
 
-    cols0 = jnp.full((Bt, k), S, jnp.int32)
-    take0 = jnp.zeros((Bt, k), jnp.int32)
-    _, cols, take = jax.lax.fori_loop(0, rounds, round_, (s, cols0, take0))
-    cols_ref[...] = cols
-    take_ref[...] = take
-    cnt_ref[...] = take.sum(axis=1, dtype=jnp.int32)[:, None]
+    nv0 = jnp.full((Bt, k), -jnp.inf, jnp.float32)
+    nc0 = jnp.full((Bt, k), S, jnp.int32)
+    _, _, _, nv, nc = jax.lax.fori_loop(
+        0, rounds, round_, (s, val_ref[...], cols_ref[...], nv0, nc0))
+    val_ref[...] = nv
+    cols_ref[...] = nc
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        take = (lane_iota < q[:, None]) & (nv > -jnp.inf)
+        cols_ref[...] = jnp.where(take, nc, S)
+        take_ref[...] = take.astype(jnp.int32)
+        cnt_ref[...] = take.astype(jnp.int32).sum(axis=1,
+                                                  dtype=jnp.int32)[:, None]
 
 
 def seg_topk_tpu(score, valid, quotas, *, k: int, block_rows: int = 8,
-                 interpret: bool = False):
+                 block_cols: int = 128, interpret: bool = False):
     """score [T, S] f32, valid [T, S] int32, quotas [T, 1] int32; T must be
-    a multiple of ``block_rows`` (ops wrapper pads). Returns
-    (cols [T, k] i32 with sentinel S, take [T, k] i32, counts [T, 1] i32)."""
+    a multiple of ``block_rows`` and S of ``block_cols`` (ops wrapper pads).
+    The grid walks row blocks in parallel and column blocks in order, so
+    VMEM holds one [block_rows, block_cols] tile per input at any S.
+    Returns (cols [T, k] i32 with sentinel S, take [T, k] i32,
+    counts [T, 1] i32)."""
     T, S = score.shape
-    Bt = block_rows
-    grid = (T // Bt,)
+    Bt, Sc = block_rows, block_cols
     return pl.pallas_call(
-        functools.partial(_seg_topk_kernel, k=k),
-        grid=grid,
+        functools.partial(_seg_topk_kernel, k=k, S=S),
+        grid=(T // Bt, S // Sc),
         in_specs=[
-            pl.BlockSpec((Bt, S), lambda i: (i, 0)),
-            pl.BlockSpec((Bt, S), lambda i: (i, 0)),
-            pl.BlockSpec((Bt, 1), lambda i: (i, 0)),
+            pl.BlockSpec((Bt, Sc), lambda i, j: (i, j)),
+            pl.BlockSpec((Bt, Sc), lambda i, j: (i, j)),
+            pl.BlockSpec((Bt, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((Bt, k), lambda i: (i, 0)),
-            pl.BlockSpec((Bt, k), lambda i: (i, 0)),
-            pl.BlockSpec((Bt, 1), lambda i: (i, 0)),
+            pl.BlockSpec((Bt, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((Bt, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((Bt, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((T, k), jnp.int32),
             jax.ShapeDtypeStruct((T, k), jnp.int32),
             jax.ShapeDtypeStruct((T, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(("parallel",)),
+        scratch_shapes=[pltpu.VMEM((Bt, k), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(score, valid, quotas)
 
@@ -144,7 +166,8 @@ def seg_reduce_tpu(x, valid, *, block_rows: int = 8,
             jax.ShapeDtypeStruct((T, 1), jnp.int32),
             jax.ShapeDtypeStruct((T, S), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, valid)
 
@@ -163,6 +186,7 @@ def seg_sums_tpu(x, valid, *, block_rows: int = 8,
         ],
         out_specs=pl.BlockSpec((Bt, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, 1), jnp.int32),
-        compiler_params=tpu_compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, valid)

@@ -17,21 +17,28 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode, pad_up
 from repro.kernels.select.kernel import (seg_reduce_tpu, seg_sums_tpu,
                                          seg_topk_tpu)
 from repro.kernels.select.ref import (seg_reduce_ref, seg_sums_ref,
                                       seg_topk_ref)
 
 _LANE = 128
+_MAX_COLS = 4096   # seg_topk column tile: [8, 4096] f32 = 128 KiB of VMEM
 
 
-def _pad_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
+def _col_block(sp: int) -> int:
+    """Widest lane-aligned divisor of the padded row width up to
+    ``_MAX_COLS``: one tile for tenant rows that fit, a column walk for
+    the dynamic strategy's whole-pool rows (S = L)."""
+    n = sp // _LANE
+    return _LANE * max(d for d in range(1, _MAX_COLS // _LANE + 1)
+                       if n % d == 0)
 
 
 def _pad_rows(score, valid, block_rows):
     T, S = score.shape
-    Tp, Sp = _pad_up(T, block_rows), _pad_up(S, _LANE)
+    Tp, Sp = pad_up(T, block_rows), pad_up(S, _LANE)
     if (Tp, Sp) == (T, S):
         return score, valid
     score = jnp.pad(score, ((0, Tp - T), (0, Sp - S)))
@@ -56,7 +63,7 @@ def seg_topk(score, valid, quotas, k: int, *, impl: str = "ref",
     q = jnp.zeros((Tp, 1), jnp.int32).at[:T, 0].set(quotas.astype(jnp.int32))
     cols, take, counts = seg_topk_tpu(
         score_p, elig_p, q, k=k, block_rows=block_rows,
-        interpret=(impl == "pallas_interpret"))
+        block_cols=_col_block(score_p.shape[1]), interpret=interpret_mode(impl))
     # padded-width sentinel (Sp) -> logical sentinel (S); real cols are < S
     cols = jnp.minimum(cols[:T], S)
     return cols, take[:T].astype(bool), counts[:T, 0]
@@ -72,7 +79,7 @@ def seg_reduce(x, valid, *, impl: str = "ref", block_rows: int = 8):
         return seg_reduce_ref(x, valid)
     x_p, valid_p = _pad_rows(x, valid.astype(jnp.int32), block_rows)
     sums, pre = seg_reduce_tpu(x_p, valid_p, block_rows=block_rows,
-                               interpret=(impl == "pallas_interpret"))
+                               interpret=interpret_mode(impl))
     return sums[:T, 0], pre[:T, :S]
 
 
@@ -85,5 +92,5 @@ def seg_sums(x, valid, *, impl: str = "ref", block_rows: int = 8):
         return seg_sums_ref(x, valid)
     x_p, valid_p = _pad_rows(x, valid.astype(jnp.int32), block_rows)
     sums = seg_sums_tpu(x_p, valid_p, block_rows=block_rows,
-                        interpret=(impl == "pallas_interpret"))
+                        interpret=interpret_mode(impl))
     return sums[:T, 0]

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_s, *,
                 nc: int, chunk: int):
@@ -91,7 +89,8 @@ def ssd_scan_tpu(x, a, b, c, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, at, bt, ct)
     return y.transpose(0, 2, 1, 3), hf

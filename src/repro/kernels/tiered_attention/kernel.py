@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -149,7 +148,8 @@ def pool_attention_partial_tpu(q, pool_k, pool_v, slot_page, seq_len, *,
             pltpu.VMEM((H,), jnp.float32),
             pltpu.VMEM((H,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(seq_len, q, pool_k, pool_v, slot_page)
     return acc, m, l, mass, mstab

@@ -11,9 +11,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis carries data parallelism across the pod-interconnect (DCN)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh over the real local device (smoke tests, examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the model's with_sharding_constraint hints steer GSPMD
+    # (``jax.make_mesh`` defaults to Explicit axes, which refuse them)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -19,11 +20,54 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import TieringConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.params import init_params
 from repro.models.transformer import model_specs
 from repro.serve.decode import build_serve_step, init_serve_state
 from repro.sharding.context import set_mesh_context
+
+
+class ServeRun(NamedTuple):
+    params: dict
+    tokens: jax.Array       # [B, steps] int32: the token fed at each step
+    logits: jax.Array       # [B, steps, V]: the step's output logits
+    state: dict             # final serve state
+    seconds: float          # wall time of the decode loop, compile included
+
+
+def run_server(cfg, *, tenants: int = 4, batch: int = 8, steps: int = 64,
+               page_tokens: int = 4, mode: str = "equilibria",
+               protection: int = 8, bound: int = 0,
+               production: bool = False) -> ServeRun:
+    """Build the tiered-KV server for ``cfg`` with seeded random weights
+    and decode ``steps`` tokens greedily for ``batch`` sequences, starting
+    from one random token each."""
+    tcfg = TieringConfig(
+        n_tenants=tenants, page_tokens=page_tokens,
+        thrash_table_slots=256,
+        lower_protection=(protection,) * tenants,
+        upper_bound=(bound,) * tenants)
+    mesh = make_production_mesh() if production else make_host_mesh()
+    set_mesh_context(mesh)
+
+    with mesh:
+        params = init_params(jax.random.PRNGKey(0), model_specs(cfg))
+        state = init_serve_state(cfg, tcfg, batch, steps)
+        step = jax.jit(build_serve_step(cfg, tcfg, batch, steps, mode=mode))
+        key = jax.random.PRNGKey(1)
+        tokens = jax.random.randint(key, (batch, 1), 0, cfg.vocab_size)
+        fed, outs = [], []
+        t0 = time.time()
+        for _ in range(steps):
+            logits, state = step(params, state, tokens)
+            fed.append(tokens)
+            outs.append(logits)
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        jax.block_until_ready(logits)
+        dt = time.time() - t0
+    return ServeRun(params, jnp.concatenate(fed, axis=1),
+                    jnp.concatenate(outs, axis=1), state, dt)
 
 
 def main():
@@ -42,28 +86,13 @@ def main():
     ap.add_argument("--bound", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    tcfg = TieringConfig(
-        n_tenants=args.tenants, page_tokens=args.page_tokens,
-        thrash_table_slots=256,
-        lower_protection=(args.protection,) * args.tenants,
-        upper_bound=(args.bound,) * args.tenants)
-    mesh = make_production_mesh() if args.production else make_host_mesh()
-    set_mesh_context(mesh)
-
-    with mesh:
-        params = init_params(jax.random.PRNGKey(0), model_specs(cfg))
-        state = init_serve_state(cfg, tcfg, args.batch, args.steps)
-        step = jax.jit(build_serve_step(cfg, tcfg, args.batch, args.steps,
-                                        mode=args.mode))
-        key = jax.random.PRNGKey(1)
-        tokens = jax.random.randint(key, (args.batch, 1), 0, cfg.vocab_size)
-        t0 = time.time()
-        for i in range(args.steps):
-            logits, state = step(params, state, tokens)
-            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(logits)
-        dt = time.time() - t0
+    run = run_server(cfg, tenants=args.tenants, batch=args.batch,
+                     steps=args.steps, page_tokens=args.page_tokens,
+                     mode=args.mode, protection=args.protection,
+                     bound=args.bound, production=args.production)
+    state, dt = run.state, run.seconds
 
     print(f"arch={cfg.name} mode={args.mode} decoded "
           f"{args.steps} tokens x {args.batch} seqs in {dt:.2f}s "

@@ -591,8 +591,9 @@ def fleet_rollout(cfg: TieringConfig, want: np.ndarray, rates: np.ndarray,
     Execution is chunked: one jitted ``lax.scan`` of ``chunk`` ticks with
     the fleet state donated between chunks (XLA reuses the carry buffers;
     per-tick outputs are reduced inside the scan to [H] running sums).
-    With more than one local device and H divisible by the device count,
-    chunks run under ``pmap`` with hosts sharded across devices.
+    With more than one local device and ``shard=True``, chunks run under
+    ``pmap`` with hosts sharded across devices; H must then divide over the
+    device count (ValueError otherwise — ``shard=False`` runs on one).
 
     ``warmup=True`` runs one throwaway chunk on a scratch fleet state
     before the timed rollout so ``elapsed_s`` measures steady-state
@@ -635,7 +636,11 @@ def fleet_rollout(cfg: TieringConfig, want: np.ndarray, rates: np.ndarray,
 
     chunk = max(min(chunk, ticks), 1)
     D = jax.local_device_count()
-    use_pmap = bool(shard) and D > 1 and H % D == 0
+    use_pmap = bool(shard) and D > 1
+    if use_pmap and H % D:
+        raise ValueError(f"fleet_rollout shards hosts across devices: "
+                         f"H={H} hosts do not divide over D={D} devices; "
+                         f"pass shard=False to run on one device")
     states = stack_states(init_state(cfg, L, detector=det_spec,
                                      attrib=att_spec), H)
     if use_pmap:

@@ -193,6 +193,18 @@ def test_rollout_archetype_tiling_matches_explicit_hosts():
     assert not np.array_equal(ct.allocations[0], ct.allocations[1])
 
 
+def test_rollout_refuses_uneven_shard(monkeypatch):
+    """With several devices, a host count that does not divide over them
+    is an error naming both, not a silent one-device run."""
+    cfg = _cfg()
+    want, rates = stack_schedules(
+        [build_churn_schedule(s, 8) for s in _hosts()])
+    monkeypatch.setattr(jax, "local_device_count", lambda: 4)
+    with pytest.raises(ValueError, match="H=3 .* D=4"):
+        fleet_rollout(cfg, want, rates, 8, host_arch=np.arange(3) % 2,
+                      chunk=4, k_max=8)
+
+
 @pytest.mark.slow
 def test_rollout_pmap_shard_path_matches():
     """With >1 device the rollout shards hosts via pmap; results are

@@ -278,6 +278,20 @@ def test_dynamic_strategy_holes_bit_exact(seed):
     np.testing.assert_array_equal(np.asarray(b.counts), np.asarray(a.counts))
 
 
+@pytest.mark.parametrize("build", [
+    lambda impl: S.static_strategy(np.zeros(8, np.int32), 1, 4, impl=impl),
+    lambda impl: S.dynamic_strategy(1, 4, impl=impl),
+    lambda impl: S.pallas_static_strategy(np.zeros(8, np.int32), 1, 4, impl),
+    lambda impl: S.pallas_dynamic_strategy(1, 4, impl),
+    lambda impl: seg_sums(jnp.zeros((1, 8), jnp.int32),
+                          jnp.ones((1, 8), bool), impl=impl),
+], ids=["static", "dynamic", "pallas_static", "pallas_dynamic", "kernel"])
+def test_unknown_impl_raises(build):
+    """A misspelt backend is an error, never a silent interpret-mode run."""
+    with pytest.raises(ValueError, match="impl"):
+        build("palas")
+
+
 # ----------------------------------------------------------- whole engine ----
 def _assert_simresult_equal(a, b):
     for f in ("fast_usage", "slow_usage", "promotions", "demotions",

@@ -128,3 +128,23 @@ def test_tpp_mode_on_serving_path():
         logits, state = step(params, state, toks[:, i:i + 1])
     ref, _ = model_forward(params, {"tokens": toks}, cfg, remat="none")
     assert float(jnp.abs(logits[:, 0] - ref[:, -1]).max()) < 1e-3
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits at
+    a fixed <checkout>/.jax_cache, never at a per-run path."""
+    import pathlib
+
+    from repro.launch.compile_cache import enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        checkout = pathlib.Path(__file__).resolve().parents[1]
+        assert path == str(checkout / ".jax_cache") == enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
