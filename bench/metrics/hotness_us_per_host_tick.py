@@ -1,0 +1,7 @@
+"""Own device time under the tick's ``hotness`` scope per host-tick traced."""
+
+from bench.stage_reduce import us_per_host_tick
+
+
+def read(ctx):
+    return us_per_host_tick(ctx, "hotness")

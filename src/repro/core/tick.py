@@ -66,6 +66,15 @@ from repro.obs import trace as OT
 
 MODES = ("equilibria", "tpp", "memtis", "static")
 
+# The tick's stages. Every op of the tick runs under ``named_scope("tick")``
+# and exactly one of these scopes below it, so the HLO ``op_name`` of each
+# compiled op reads ``.../tick/<stage>/...`` and a device trace's own time
+# splits by stage. ``select`` and ``commit`` nest a site scope (``demote``,
+# ``promote``, ``sync_demote``), the dynamic provider's ``ownership`` one
+# per lifecycle block.
+STAGES = ("ownership", "alloc", "hotness", "regulate", "select", "commit",
+          "telemetry", "control")
+
 
 class TickOutput(NamedTuple):
     fast_usage: jax.Array      # [T] pages
@@ -201,65 +210,74 @@ def dynamic_ownership(cfg: TieringConfig, n_pages: int, k_max: int,
         S = rates.shape[1]
         t = state.t
         owner = state.owner
-        tier = state.tier.astype(jnp.int32)
-        hot = state.hot
-        want = want.astype(jnp.int32)
-        active = want > 0
 
         # ---- reclaim (departure & shrink), coldest-first ----------------
-        owned = owner < FREE
-        cnt = strategy.by_tenant(owned.astype(jnp.int32), owner)
-        delta = want - cnt
-        arrived = (cnt == 0) & (delta > 0)
-        release_q = jnp.minimum(jnp.maximum(-delta, 0), cnt)
-        cold0 = HOT.cold_score(t, state.last_access, hot)
-        # k_cap = L: a departing tenant frees its whole footprint this tick
-        reclaimed = SEL.select_top_quota(cold0, owner, owned, release_q, T, L)
-        owner_c = jnp.minimum(owner, T - 1)
-        rec_fast = reclaimed & (tier == TIER_FAST)
-        # reclaims are event-driven (departure/shrink ticks only): cond-skip
-        # the [L]-lane residency scatter on quiet ticks (empty-mask no-op)
-        stats = jax.lax.cond(
-            rec_fast.any(),
-            lambda s: OS.record_fast_exits(s, rec_fast, owner_c, t),
-            lambda s: s, state.stats)
-        freed_t = strategy.by_tenant(reclaimed.astype(jnp.int32), owner)
-        owner = jnp.where(reclaimed, FREE, owner)
-        tier = jnp.where(reclaimed, TIER_NONE, tier)
-        hot = jnp.where(reclaimed, 0.0, hot)
-        # a reclaimed page's thrash-table entry is stale: without this, a
-        # page promoted by the old tenant and re-granted soon after would
-        # count a false thrash hit against its new owner
-        tp = state.table.page
-        stale = (tp >= 0) & reclaimed[jnp.maximum(tp, 0)]
-        table = ThrashTable(page=jnp.where(stale, -1, tp),
-                            tick=jnp.where(stale, 0, state.table.tick))
+        with jax.named_scope("reclaim"):
+            tier = state.tier.astype(jnp.int32)
+            hot = state.hot
+            want = want.astype(jnp.int32)
+            active = want > 0
+            owned = owner < FREE
+            cnt = strategy.by_tenant(owned.astype(jnp.int32), owner)
+            delta = want - cnt
+            arrived = (cnt == 0) & (delta > 0)
+            release_q = jnp.minimum(jnp.maximum(-delta, 0), cnt)
+            cold0 = HOT.cold_score(t, state.last_access, hot)
+            # k_cap = L: a departing tenant frees its whole footprint now
+            reclaimed = SEL.select_top_quota(cold0, owner, owned, release_q,
+                                             T, L)
+            owner_c = jnp.minimum(owner, T - 1)
+            rec_fast = reclaimed & (tier == TIER_FAST)
+            # reclaims are event-driven (departure/shrink ticks only): cond-
+            # skip the [L]-lane residency scatter on quiet ticks (empty-mask
+            # no-op)
+            stats = jax.lax.cond(
+                rec_fast.any(),
+                lambda s: OS.record_fast_exits(s, rec_fast, owner_c, t),
+                lambda s: s, state.stats)
+            freed_t = strategy.by_tenant(reclaimed.astype(jnp.int32), owner)
+            owner = jnp.where(reclaimed, FREE, owner)
+            tier = jnp.where(reclaimed, TIER_NONE, tier)
+            hot = jnp.where(reclaimed, 0.0, hot)
+            # a reclaimed page's thrash-table entry is stale: without this,
+            # a page promoted by the old tenant and re-granted soon after
+            # would count a false thrash hit against its new owner
+            tp = state.table.page
+            stale = (tp >= 0) & reclaimed[jnp.maximum(tp, 0)]
+            table = ThrashTable(page=jnp.where(stale, -1, tp),
+                                tick=jnp.where(stale, 0, state.table.tick))
 
         # ---- grant from the free pool -----------------------------------
-        need = jnp.maximum(delta, 0)
-        grant_owner = SEL.pool_grant(owner == FREE, need)
-        granted = grant_owner < FREE
-        owner = jnp.where(granted, grant_owner, owner)
-        owner_c = jnp.minimum(owner, T - 1)
-        owned = owner < FREE
+        with jax.named_scope("grant"):
+            need = jnp.maximum(delta, 0)
+            grant_owner = SEL.pool_grant(owner == FREE, need)
+            granted = grant_owner < FREE
+            owner = jnp.where(granted, grant_owner, owner)
+            owner_c = jnp.minimum(owner, T - 1)
+            owned = owner < FREE
 
         # ---- slot reuse: fresh arrivals get clean controller state ------
-        promo_scale0 = jnp.where(arrived, 1.0, state.promo_scale)
-        steady0 = jnp.where(arrived, False, state.steady)
-        mitigated0 = jnp.where(arrived, False, state.mitigated_prev)
-        thrash_prev0 = jnp.where(arrived, state.counters.thrash_events,
-                                 state.thrash_prev)
-        usage_prev0 = jnp.where(arrived, 0, state.usage_prev)
-        freed_since0 = jnp.where(arrived, 0, state.freed_since + freed_t)
+        with jax.named_scope("slot_reuse"):
+            promo_scale0 = jnp.where(arrived, 1.0, state.promo_scale)
+            steady0 = jnp.where(arrived, False, state.steady)
+            mitigated0 = jnp.where(arrived, False, state.mitigated_prev)
+            thrash_prev0 = jnp.where(arrived, state.counters.thrash_events,
+                                     state.thrash_prev)
+            usage_prev0 = jnp.where(arrived, 0, state.usage_prev)
+            freed_since0 = jnp.where(arrived, 0,
+                                     state.freed_since + freed_t)
 
         # ---- per-page accesses from the tenant-local schedule -----------
-        prank = SEL.segment_ranks(jnp.where(owned, owner, T),
-                                  jnp.zeros((L,), jnp.int32), T)
-        accesses = jnp.where(
-            owned, rates[owner_c, jnp.minimum(prank, S - 1)], 0.0)
+        with jax.named_scope("schedule"):
+            prank = SEL.segment_ranks(jnp.where(owned, owner, T),
+                                      jnp.zeros((L,), jnp.int32), T)
+            accesses = jnp.where(
+                owned, rates[owner_c, jnp.minimum(prank, S - 1)], 0.0)
 
         # ---- policy re-partition on membership --------------------------
-        pol = P.repartition_policy(base_pol, active, n_fast - wmark, weights)
+        with jax.named_scope("repartition"):
+            pol = P.repartition_policy(base_pol, active, n_fast - wmark,
+                                       weights)
 
         # tenant rowspace from the live owner vector, built only when a
         # hotness provider asks (one [T, S] scatter; the exact provider's
@@ -330,12 +348,15 @@ def make_tick_core(cfg: TieringConfig, provider: OwnershipProvider,
     alloc_ranks = strategy.alloc_ranks
     hot_provider = HOT.resolve_hotness(hotness, cfg, L, k_max)
 
+    @jax.named_scope("tick")
     def tick(state: TierState, inputs) -> Tuple[TierState, TickOutput]:
         t = state.t
-        page_ids = jnp.arange(L, dtype=jnp.int32)
+        with jax.named_scope("commit"):   # the helpers' [L] lane fallback
+            page_ids = jnp.arange(L, dtype=jnp.int32)
 
         # ---- 1. ownership / lifecycle (the provider seam) -----------------
-        prep = provider.prepare(state, inputs)
+        with jax.named_scope("ownership"):
+            prep = provider.prepare(state, inputs)
         owner, owner_c = prep.owner, prep.owner_c
         alive, accesses = prep.alive, prep.accesses
         tier, stats, ring = prep.tier, prep.stats, prep.ring
@@ -345,6 +366,7 @@ def make_tick_core(cfg: TieringConfig, provider: OwnershipProvider,
         # ring) runs over the selection's compact [T, k] candidate stream
         # when available (contiguous batched path) — scatters over T*k lanes
         # instead of L — and falls back to the full [L] masks otherwise.
+        # These helpers run under the ``commit`` scope of their call site.
         def sel_counts(sel: SEL.Selection) -> jax.Array:
             if sel.counts is not None:
                 return sel.counts
@@ -400,268 +422,299 @@ def make_tick_core(cfg: TieringConfig, provider: OwnershipProvider,
         # the entry stamps — runs under a cond. With ``new`` empty every
         # branch output equals the pass-through (wheres over a False mask,
         # a zero by_tenant, an empty entry stamp), so values are unchanged.
-        new = alive & (tier == TIER_NONE)
-        fast_usage = by_tenant((tier == TIER_FAST).astype(jnp.int32), owner)
-        fast_free = n_fast - fast_usage.sum()
+        with jax.named_scope("alloc"):
+            new = alive & (tier == TIER_NONE)
+            fast_usage = by_tenant((tier == TIER_FAST).astype(jnp.int32),
+                                   owner)
+            fast_free = n_fast - fast_usage.sum()
 
-        def do_alloc(args):
-            tier_, stats_ = args
-            alloc_ = None
-            # per-tenant upper bound gating of *fast* placement
-            if mode in ("equilibria", "memtis") and cfg.enable_upper_bound:
-                if strategy.alloc_stats is not None:
-                    # fused kernel pass: allocation ranks + per-tenant new-
-                    # page counts from one segmented reduction
-                    ranks, alloc_ = strategy.alloc_stats(new, owner)
+            def do_alloc(args):
+                tier_, stats_ = args
+                alloc_ = None
+                # per-tenant upper bound gating of *fast* placement
+                if mode in ("equilibria", "memtis") and cfg.enable_upper_bound:
+                    if strategy.alloc_stats is not None:
+                        # fused kernel pass: allocation ranks + per-tenant
+                        # new-page counts from one segmented reduction
+                        ranks, alloc_ = strategy.alloc_stats(new, owner)
+                    else:
+                        ranks = alloc_ranks(new, owner)
+                    bound = pol.upper_bound[owner_c]
+                    under_bound = ((bound == 0)
+                                   | (fast_usage[owner_c] + ranks < bound))
                 else:
-                    ranks = alloc_ranks(new, owner)
-                bound = pol.upper_bound[owner_c]
-                under_bound = ((bound == 0)
-                               | (fast_usage[owner_c] + ranks < bound))
-            else:
-                under_bound = jnp.ones((L,), bool)
-            elig = new & under_bound
-            grank = SEL.masked_rank(elig)
-            go_fast = elig & (grank < jnp.maximum(fast_free - wmark, 0))
-            tier_ = jnp.where(go_fast, TIER_FAST,
-                              jnp.where(new, TIER_SLOW, tier_))
-            if alloc_ is None:
-                alloc_ = by_tenant(new.astype(jnp.int32), owner)
-            return tier_, alloc_, OS.record_fast_entries(stats_, go_fast, t)
+                    under_bound = jnp.ones((L,), bool)
+                elig = new & under_bound
+                grank = SEL.masked_rank(elig)
+                go_fast = elig & (grank < jnp.maximum(fast_free - wmark, 0))
+                tier_ = jnp.where(go_fast, TIER_FAST,
+                                  jnp.where(new, TIER_SLOW, tier_))
+                if alloc_ is None:
+                    alloc_ = by_tenant(new.astype(jnp.int32), owner)
+                return tier_, alloc_, OS.record_fast_entries(stats_, go_fast,
+                                                             t)
 
-        tier, alloc_t, stats = jax.lax.cond(
-            new.any(), do_alloc,
-            lambda args: (args[0], jnp.zeros((T,), jnp.int32), args[1]),
-            (tier, stats))
+            tier, alloc_t, stats = jax.lax.cond(
+                new.any(), do_alloc,
+                lambda args: (args[0], jnp.zeros((T,), jnp.int32), args[1]),
+                (tier, stats))
 
         # ---- 3. hotness / recency (the hotness-provider seam) -------------
-        last_access = jnp.where(new | (accesses > 0), t, state.last_access)
-        hview = hot_provider.step(HOT.HotCtx(
-            hstate=state.hotness, prev_hot=prep.hot, accesses=accesses,
-            alive=alive, new=new, tier=tier, last_access=last_access,
-            owner=owner, owner_c=owner_c, t=t, rows=prep.rows,
-            strategy=provider.strategy))
+        with jax.named_scope("hotness"):
+            last_access = jnp.where(new | (accesses > 0), t,
+                                    state.last_access)
+            hview = hot_provider.step(HOT.HotCtx(
+                hstate=state.hotness, prev_hot=prep.hot, accesses=accesses,
+                alive=alive, new=new, tier=tier, last_access=last_access,
+                owner=owner, owner_c=owner_c, t=t, rows=prep.rows,
+                strategy=provider.strategy))
         hot = hview.hot
 
         # ---- 4. contention ------------------------------------------------
         # Local memory is contended when free space cannot absorb both the
         # watermark and the pending promotion demand (kswapd-style: promotion
         # pressure drives background demotion, §IV-D).
-        fast_usage = by_tenant((tier == TIER_FAST).astype(jnp.int32), owner)
-        fast_free = n_fast - fast_usage.sum()
-        demand_t = jnp.minimum(hview.demand_t, k_max)
-        promo_demand = jnp.minimum(demand_t.sum(), k_max)
-        contended = fast_free < wmark + promo_demand
+        with jax.named_scope("regulate"):
+            fast_usage = by_tenant((tier == TIER_FAST).astype(jnp.int32),
+                                   owner)
+            fast_free = n_fast - fast_usage.sum()
+            demand_t = jnp.minimum(hview.demand_t, k_max)
+            promo_demand = jnp.minimum(demand_t.sum(), k_max)
+            contended = fast_free < wmark + promo_demand
 
-        # ---- 5. demotion ---------------------------------------------------
-        sync_quota = jnp.zeros((T,), jnp.int32)
-        if mode == "equilibria":
-            d_scan = P.eq1_demotion_scan(fast_usage, fast_usage, pol, contended)
-            if not cfg.enable_protection:
-                # ablation: proportional pressure without protection
-                d_scan = jnp.where(contended, fast_usage.astype(jnp.float32),
-                                   0.0)
-            # Eq.1 sets each tenant's *share* of reclaim work; the total is
-            # kswapd-style demand-driven: free enough for the watermark plus
-            # pending promotions, no more (work-conserving donation, §V-B3).
-            # A tenant's OWN promotion demand never drives its own demotion
-            # (that would be pure churn); only neighbors' demand evicts it.
-            demand_other = jnp.minimum(promo_demand - demand_t, k_max)
-            needed_t = jnp.maximum(wmark + demand_other - fast_free, 0)
-            total_scan = jnp.maximum(d_scan.sum(), 1.0)
-            share = jnp.ceil(d_scan * jnp.minimum(
-                needed_t.astype(jnp.float32) / total_scan, 1.0)).astype(jnp.int32)
-            if cfg.enable_upper_bound:
+            # ---- 5. demotion -----------------------------------------------
+            sync_quota = jnp.zeros((T,), jnp.int32)
+            if mode == "equilibria":
+                d_scan = P.eq1_demotion_scan(fast_usage, fast_usage, pol,
+                                             contended)
+                if not cfg.enable_protection:
+                    # ablation: proportional pressure without protection
+                    d_scan = jnp.where(contended,
+                                       fast_usage.astype(jnp.float32), 0.0)
+                # Eq.1 sets each tenant's *share* of reclaim work; the total
+                # is kswapd-style demand-driven: free enough for the
+                # watermark plus pending promotions, no more (work-conserving
+                # donation, §V-B3). A tenant's OWN promotion demand never
+                # drives its own demotion (that would be pure churn); only
+                # neighbors' demand evicts it.
+                demand_other = jnp.minimum(promo_demand - demand_t, k_max)
+                needed_t = jnp.maximum(wmark + demand_other - fast_free, 0)
+                total_scan = jnp.maximum(d_scan.sum(), 1.0)
+                share = jnp.ceil(d_scan * jnp.minimum(
+                    needed_t.astype(jnp.float32) / total_scan, 1.0)
+                ).astype(jnp.int32)
+                if cfg.enable_upper_bound:
+                    sync_quota = P.upper_bound_demotion(fast_usage, pol)
+                quota = jnp.minimum(share + sync_quota, k_max)
+            elif mode == "tpp":
+                needed = jnp.maximum(2 * wmark - fast_free, 0)
+                quota = jnp.minimum(needed, k_max * T)  # global
+            elif mode == "memtis":
                 sync_quota = P.upper_bound_demotion(fast_usage, pol)
-            quota = jnp.minimum(share + sync_quota, k_max)
-        elif mode == "tpp":
-            needed = jnp.maximum(2 * wmark - fast_free, 0)
-            quota = jnp.minimum(needed, k_max * T)  # global
-        elif mode == "memtis":
-            sync_quota = P.upper_bound_demotion(fast_usage, pol)
-            quota = jnp.minimum(sync_quota, k_max)
-        else:  # static
-            quota = jnp.zeros((T,), jnp.int32)
+                quota = jnp.minimum(sync_quota, k_max)
+            else:  # static
+                quota = jnp.zeros((T,), jnp.int32)
 
-        fast_mask = tier == TIER_FAST
-        if mode == "tpp":
-            dsel = hview.demote_global(fast_mask, quota)
-        elif mode == "static":
-            dsel = SEL.Selection(jnp.zeros((L,), bool), None, None, None)
-        else:
-            dsel = hview.demote(fast_mask, quota)
+        with jax.named_scope("select"), jax.named_scope("demote"):
+            fast_mask = tier == TIER_FAST
+            if mode == "tpp":
+                dsel = hview.demote_global(fast_mask, quota)
+            elif mode == "static":
+                dsel = SEL.Selection(jnp.zeros((L,), bool), None, None, None)
+            else:
+                dsel = hview.demote(fast_mask, quota)
         demoted = dsel.mask
-        demo_t = sel_counts(dsel)
 
-        # thrash detection on demotions (§IV-F)
-        thrash_new = sel_thrash(prep.table, dsel)
-        stats = sel_exits(stats, dsel)
-        tier, ring = move_pages(tier, ring, dsel, hot, OT.DIR_DEMOTE,
-                                TIER_SLOW)
-        fast_usage = fast_usage - demo_t
-        fast_free = n_fast - fast_usage.sum()
+        with jax.named_scope("commit"), jax.named_scope("demote"):
+            demo_t = sel_counts(dsel)
+            # thrash detection on demotions (§IV-F)
+            thrash_new = sel_thrash(prep.table, dsel)
+            stats = sel_exits(stats, dsel)
+            tier, ring = move_pages(tier, ring, dsel, hot, OT.DIR_DEMOTE,
+                                    TIER_SLOW)
+        with jax.named_scope("regulate"):
+            fast_usage = fast_usage - demo_t
+            fast_free = n_fast - fast_usage.sum()
 
         # ---- 6. promotion ---------------------------------------------------
         # just-demoted pages are not promotion candidates this tick
-        pcand = hview.promo_cand(tier, demoted)
+        with jax.named_scope("select"), jax.named_scope("promote"):
+            pcand = hview.promo_cand(tier, demoted)
         cand_t = pcand.cand_t
-        throttled = jnp.zeros((T,), bool)
-        q_base = q_eq2 = q_mit = None   # attribution quota cascade (9c)
-        if mode == "equilibria":
-            p_base = jnp.full((T,), float(cfg.p_base), jnp.float32)
-            if cfg.enable_promo_throttle:
-                p_scan, throttled = P.eq2_promotion_scan(p_base, fast_usage,
-                                                         pol, contended, cfg)
+        with jax.named_scope("regulate"):
+            throttled = jnp.zeros((T,), bool)
+            q_base = q_eq2 = q_mit = None   # attribution quota cascade (9c)
+            if mode == "equilibria":
+                p_base = jnp.full((T,), float(cfg.p_base), jnp.float32)
+                if cfg.enable_promo_throttle:
+                    p_scan, throttled = P.eq2_promotion_scan(
+                        p_base, fast_usage, pol, contended, cfg)
+                else:
+                    p_scan = p_base
+                p_eq2 = p_scan                        # pre-mitigation scan
+                p_scan = p_scan * prep.promo_scale    # thrash mitigation
+                p_quota = jnp.minimum(p_scan.astype(jnp.int32), k_max)
+                if attrib is not None:
+                    # telescoping quota cascade: each stage capped the same
+                    # way the pipeline caps p_quota below (min with cand and
+                    # k_max), so successive differences are the deferral
+                    # components
+                    c0 = jnp.minimum(cand_t, k_max)
+                    q_base = jnp.minimum(jnp.full((T,), int(cfg.p_base),
+                                                  jnp.int32), c0)
+                    q_eq2 = jnp.minimum(
+                        jnp.minimum(p_eq2.astype(jnp.int32), k_max), c0)
+                    q_mit = jnp.minimum(p_quota, c0)
+            elif mode in ("tpp", "memtis"):
+                p_quota = jnp.full((T,), cfg.p_base, jnp.int32)  # unregulated
+                if attrib is not None:
+                    # no throttle / mitigation stages: the whole cascade is
+                    # the unregulated scan budget
+                    q_base = q_eq2 = q_mit = jnp.minimum(
+                        p_quota, jnp.minimum(cand_t, k_max))
             else:
-                p_scan = p_base
-            p_eq2 = p_scan                            # pre-mitigation scan
-            p_scan = p_scan * prep.promo_scale        # thrash mitigation
-            p_quota = jnp.minimum(p_scan.astype(jnp.int32), k_max)
-            if attrib is not None:
-                # telescoping quota cascade: each stage capped the same way
-                # the pipeline caps p_quota below (min with cand and k_max),
-                # so successive differences are the deferral components
-                c0 = jnp.minimum(cand_t, k_max)
-                q_base = jnp.minimum(jnp.full((T,), int(cfg.p_base),
-                                              jnp.int32), c0)
-                q_eq2 = jnp.minimum(
-                    jnp.minimum(p_eq2.astype(jnp.int32), k_max), c0)
-                q_mit = jnp.minimum(p_quota, c0)
-        elif mode in ("tpp", "memtis"):
-            p_quota = jnp.full((T,), cfg.p_base, jnp.int32)  # unregulated
-            if attrib is not None:
-                # no throttle / mitigation stages: the whole cascade is the
-                # unregulated scan budget
-                q_base = q_eq2 = q_mit = jnp.minimum(
-                    p_quota, jnp.minimum(cand_t, k_max))
-        else:
-            p_quota = jnp.zeros((T,), jnp.int32)
-            if attrib is not None:   # no promotion path at all
-                q_base = q_eq2 = q_mit = p_quota
+                p_quota = jnp.zeros((T,), jnp.int32)
+                if attrib is not None:   # no promotion path at all
+                    q_base = q_eq2 = q_mit = p_quota
 
-        # never overfill: cap total promotions by free fast capacity.
-        # NOTE: promotions may transiently exceed a tenant's upper bound —
-        # the allocating thread then demotes synchronously in the same tick
-        # (paper §IV-D); that promote->sync-demote cycle is exactly the
-        # thrashing signature §IV-F detects.
-        p_quota = jnp.minimum(p_quota, jnp.minimum(cand_t, k_max))
-        headroom = jnp.maximum(fast_free - wmark, 0)
-        total = p_quota.sum()
-        scale = jnp.where(total > headroom,
-                          headroom.astype(jnp.float32) / jnp.maximum(total, 1),
-                          1.0)
-        p_quota = jnp.floor(p_quota.astype(jnp.float32) * scale).astype(jnp.int32)
+            # never overfill: cap total promotions by free fast capacity.
+            # NOTE: promotions may transiently exceed a tenant's upper bound
+            # — the allocating thread then demotes synchronously in the same
+            # tick (paper §IV-D); that promote->sync-demote cycle is exactly
+            # the thrashing signature §IV-F detects.
+            p_quota = jnp.minimum(p_quota, jnp.minimum(cand_t, k_max))
+            headroom = jnp.maximum(fast_free - wmark, 0)
+            total = p_quota.sum()
+            scale = jnp.where(
+                total > headroom,
+                headroom.astype(jnp.float32) / jnp.maximum(total, 1), 1.0)
+            p_quota = jnp.floor(p_quota.astype(jnp.float32) * scale
+                                ).astype(jnp.int32)
 
-        if mode == "tpp":
-            psel = pcand.select_global(p_quota.sum())
-        elif mode == "static":
-            psel = SEL.Selection(jnp.zeros((L,), bool), None, None, None)
-        else:
-            psel = pcand.select(p_quota)
+        with jax.named_scope("select"), jax.named_scope("promote"):
+            if mode == "tpp":
+                psel = pcand.select_global(p_quota.sum())
+            elif mode == "static":
+                psel = SEL.Selection(jnp.zeros((L,), bool), None, None, None)
+            else:
+                psel = pcand.select(p_quota)
         promoted = psel.mask
-        promo_t = sel_counts(psel)
-        tier, ring = move_pages(tier, ring, psel, hot, OT.DIR_PROMOTE,
-                                TIER_FAST)
-        table = sel_record_promos(prep.table, psel)
-        stats = OS.record_fast_entries(stats, promoted, t)
+        with jax.named_scope("commit"), jax.named_scope("promote"):
+            promo_t = sel_counts(psel)
+            tier, ring = move_pages(tier, ring, psel, hot, OT.DIR_PROMOTE,
+                                    TIER_FAST)
+            table = sel_record_promos(prep.table, psel)
+            stats = OS.record_fast_entries(stats, promoted, t)
 
         # ---- 6b. synchronous upper-bound demotion (allocation path, §IV-D):
         # promotions that pushed a tenant past its bound are shed in the same
         # tick by the "allocating thread" — these demotions hit the thrash
         # table immediately when they evict recently-promoted pages.
-        sync2_t = jnp.zeros((T,), jnp.int32)
+        with jax.named_scope("commit"):
+            sync2_t = jnp.zeros((T,), jnp.int32)
         if mode in ("equilibria", "memtis") and cfg.enable_upper_bound:
-            fast_usage2 = by_tenant((tier == TIER_FAST).astype(jnp.int32),
-                                    owner)
-            over2 = jnp.where(pol.upper_bound > 0,
-                              jnp.maximum(fast_usage2 - pol.upper_bound, 0), 0)
-            over2 = jnp.minimum(over2, k_max)
-            ssel = hview.demote(tier == TIER_FAST, over2)
-            thr2 = sel_thrash(table, ssel)
-            thrash_new = thrash_new + thr2
-            stats = sel_exits(stats, ssel)
-            tier, ring = move_pages(tier, ring, ssel, hot, OT.DIR_DEMOTE,
-                                    TIER_SLOW)
-            sync2_t = sel_counts(ssel)
-            demo_t = demo_t + sync2_t
+            with jax.named_scope("regulate"):
+                fast_usage2 = by_tenant(
+                    (tier == TIER_FAST).astype(jnp.int32), owner)
+                over2 = jnp.where(
+                    pol.upper_bound > 0,
+                    jnp.maximum(fast_usage2 - pol.upper_bound, 0), 0)
+                over2 = jnp.minimum(over2, k_max)
+            with jax.named_scope("select"), jax.named_scope("sync_demote"):
+                ssel = hview.demote(tier == TIER_FAST, over2)
+            with jax.named_scope("commit"), jax.named_scope("sync_demote"):
+                thr2 = sel_thrash(table, ssel)
+                thrash_new = thrash_new + thr2
+                stats = sel_exits(stats, ssel)
+                tier, ring = move_pages(tier, ring, ssel, hot,
+                                        OT.DIR_DEMOTE, TIER_SLOW)
+                sync2_t = sel_counts(ssel)
+                demo_t = demo_t + sync2_t
 
-        # ---- 7. counters ----------------------------------------------------
-        c = state.counters
-        counters = Counters(
-            promotions=c.promotions + promo_t,
-            demotions=c.demotions + demo_t,
-            attempted_promotions=c.attempted_promotions + cand_t,
-            reclaims=c.reclaims + prep.freed_t,
-            allocations=c.allocations + alloc_t,
-            thrash_events=c.thrash_events + thrash_new,
-            sync_demotions=c.sync_demotions
-            + jnp.minimum(sync_quota, demo_t) + sync2_t,
-        )
-        fast_usage = by_tenant((tier == TIER_FAST).astype(jnp.int32), owner)
-        slow_usage = by_tenant((tier == TIER_SLOW).astype(jnp.int32), owner)
+        with jax.named_scope("telemetry"):
+            # ---- 7. counters ------------------------------------------------
+            c = state.counters
+            counters = Counters(
+                promotions=c.promotions + promo_t,
+                demotions=c.demotions + demo_t,
+                attempted_promotions=c.attempted_promotions + cand_t,
+                reclaims=c.reclaims + prep.freed_t,
+                allocations=c.allocations + alloc_t,
+                thrash_events=c.thrash_events + thrash_new,
+                sync_demotions=c.sync_demotions
+                + jnp.minimum(sync_quota, demo_t) + sync2_t,
+            )
+            fast_usage = by_tenant((tier == TIER_FAST).astype(jnp.int32),
+                                   owner)
+            slow_usage = by_tenant((tier == TIER_SLOW).astype(jnp.int32),
+                                   owner)
 
-        # ---- 7b. observability (obs/, §IV-C) --------------------------------
-        # tpp's quota is one global scan budget; split it evenly so
-        # demo_success_ratio stays comparable across modes
-        demo_att = (jnp.broadcast_to((quota + T - 1) // T, (T,))
-                    if quota.ndim == 0 else quota)
-        below_prot = OS.below_protection(fast_usage, slow_usage,
-                                         pol.lower_protection)
-        # sync upper-bound demotions (6b) bypass the step-5 quota; count them
-        # on both sides so demo_success_ratio stays <= 1
-        stats = OS.update_tick(
-            stats, promo_attempts=cand_t, promo_success=promo_t,
-            demo_attempts=jnp.minimum(demo_att, k_max) + sync2_t,
-            demo_success=demo_t,
-            thrash_new=thrash_new, contended=contended, throttled=throttled,
-            below_protection=below_prot, decay=cfg.obs_window_decay)
+            # ---- 7b. observability (obs/, §IV-C) ----------------------------
+            # tpp's quota is one global scan budget; split it evenly so
+            # demo_success_ratio stays comparable across modes
+            demo_att = (jnp.broadcast_to((quota + T - 1) // T, (T,))
+                        if quota.ndim == 0 else quota)
+            below_prot = OS.below_protection(fast_usage, slow_usage,
+                                             pol.lower_protection)
+            # sync upper-bound demotions (6b) bypass the step-5 quota; count
+            # them on both sides so demo_success_ratio stays <= 1
+            stats = OS.update_tick(
+                stats, promo_attempts=cand_t, promo_success=promo_t,
+                demo_attempts=jnp.minimum(demo_att, k_max) + sync2_t,
+                demo_success=demo_t,
+                thrash_new=thrash_new, contended=contended,
+                throttled=throttled, below_protection=below_prot,
+                decay=cfg.obs_window_decay)
 
-        new_state = TierState(
-            tier=tier.astype(jnp.int8), hot=hot, last_access=last_access,
-            owner=owner,
-            counters=counters, promo_scale=prep.promo_scale,
-            thrash_prev=prep.thrash_prev, usage_prev=prep.usage_prev,
-            freed_since=prep.freed_since, steady=prep.steady,
-            mitigated_prev=prep.mitigated_prev,
-            table=table, stats=stats, ring=ring, t=t + 1, det=state.det,
-            attrib=state.attrib, hotness=hview.hstate)
+        with jax.named_scope("control"):
+            new_state = TierState(
+                tier=tier.astype(jnp.int8), hot=hot, last_access=last_access,
+                owner=owner,
+                counters=counters, promo_scale=prep.promo_scale,
+                thrash_prev=prep.thrash_prev, usage_prev=prep.usage_prev,
+                freed_since=prep.freed_since, steady=prep.steady,
+                mitigated_prev=prep.mitigated_prev,
+                table=table, stats=stats, ring=ring, t=t + 1, det=state.det,
+                attrib=state.attrib, hotness=hview.hstate)
 
-        # ---- 8. periodic controller (§IV-F) ---------------------------------
-        def run_ctrl(s: TierState) -> TierState:
-            out = P.thrash_controller(s, fast_usage + slow_usage, cfg)
-            return s._replace(promo_scale=out.promo_scale, steady=out.steady,
-                              table=out.table, thrash_prev=out.thrash_prev,
-                              usage_prev=out.usage_prev,
-                              freed_since=out.freed_since,
-                              mitigated_prev=out.mitigated_prev)
+            # ---- 8. periodic controller (§IV-F) -----------------------------
+            def run_ctrl(s: TierState) -> TierState:
+                out = P.thrash_controller(s, fast_usage + slow_usage, cfg)
+                return s._replace(promo_scale=out.promo_scale,
+                                  steady=out.steady, table=out.table,
+                                  thrash_prev=out.thrash_prev,
+                                  usage_prev=out.usage_prev,
+                                  freed_since=out.freed_since,
+                                  mitigated_prev=out.mitigated_prev)
 
-        new_state = jax.lax.cond(
-            (t + 1) % cfg.controller_period == 0, run_ctrl, lambda s: s,
-            new_state)
+            new_state = jax.lax.cond(
+                (t + 1) % cfg.controller_period == 0, run_ctrl, lambda s: s,
+                new_state)
 
-        # ---- 9. perf model ---------------------------------------------------
-        a_fast = by_tenant(accesses * (tier == TIER_FAST), owner)
-        a_slow = by_tenant(accesses * (tier == TIER_SLOW), owner)
-        a_tot = a_fast + a_slow
-        migrations = (promo_t + demo_t).sum().astype(jnp.float32)
-        lat = jnp.where(
-            a_tot > 0,
-            (a_fast * cfg.lat_fast + a_slow * cfg.lat_slow)
-            / jnp.maximum(a_tot, 1e-9),
-            cfg.lat_fast) + migrations * cfg.migration_cost
-        thru = jnp.where(a_tot > 0, a_tot / lat, 0.0)
+            # ---- 9. perf model ----------------------------------------------
+            a_fast = by_tenant(accesses * (tier == TIER_FAST), owner)
+            a_slow = by_tenant(accesses * (tier == TIER_SLOW), owner)
+            a_tot = a_fast + a_slow
+            migrations = (promo_t + demo_t).sum().astype(jnp.float32)
+            lat = jnp.where(
+                a_tot > 0,
+                (a_fast * cfg.lat_fast + a_slow * cfg.lat_slow)
+                / jnp.maximum(a_tot, 1e-9),
+                cfg.lat_fast) + migrations * cfg.migration_cost
+            thru = jnp.where(a_tot > 0, a_tot / lat, 0.0)
 
         # ---- 9b. streaming pathology detectors (obs/streaming.py) ----------
         # fed the exact per-tick values the offline detectors read from
         # TickOutput traces, so the streamed verdicts can agree bit-for-bit
         if detector is not None:
-            new_state = new_state._replace(det=DS.update_detector(
-                detector, state.det,
-                DS.DetectorSignals(
-                    active=prep.active, thrash_new=thrash_new,
-                    fast_usage=fast_usage, slow_usage=slow_usage,
-                    attempted=cand_t, promotions=promo_t, demotions=demo_t,
-                    latency=lat), t))
+            with jax.named_scope("telemetry"):
+                new_state = new_state._replace(det=DS.update_detector(
+                    detector, state.det,
+                    DS.DetectorSignals(
+                        active=prep.active, thrash_new=thrash_new,
+                        fast_usage=fast_usage, slow_usage=slow_usage,
+                        attempted=cand_t, promotions=promo_t,
+                        demotions=demo_t, latency=lat), t))
 
         # ---- 9c. slowdown attribution ledger (obs/attribution.py) ----------
         # the promotion pipeline's quota cascade, telescoped into additive
@@ -669,21 +722,24 @@ def make_tick_core(cfg: TieringConfig, provider: OwnershipProvider,
         # bit-exact because cand_t / promo_t / freed_t are the SAME values
         # step 7 accumulates into attempted/promotions/reclaims
         if attrib is not None:
-            new_state = new_state._replace(attrib=AT.update_attribution(
-                attrib, state.attrib,
-                AT.AttribSignals(
-                    cand=cand_t, promoted=promo_t, quota_base=q_base,
-                    quota_eq2=q_eq2, quota_mit=q_mit, freed=prep.freed_t,
-                    a_fast=a_fast, a_slow=a_slow, latency=lat)))
+            with jax.named_scope("telemetry"):
+                new_state = new_state._replace(attrib=AT.update_attribution(
+                    attrib, state.attrib,
+                    AT.AttribSignals(
+                        cand=cand_t, promoted=promo_t, quota_base=q_base,
+                        quota_eq2=q_eq2, quota_mit=q_mit, freed=prep.freed_t,
+                        a_fast=a_fast, a_slow=a_slow, latency=lat)))
 
-        out = TickOutput(
-            fast_usage=fast_usage, slow_usage=slow_usage,
-            promotions=promo_t, demotions=demo_t,
-            throughput=thru, latency=lat, promo_scale=new_state.promo_scale,
-            thrash_events=counters.thrash_events,
-            fast_free=n_fast - fast_usage.sum(),
-            attempted_promotions=cand_t,
-            pool_free=provider.pool_free(owner, tier))
+        with jax.named_scope("control"):
+            out = TickOutput(
+                fast_usage=fast_usage, slow_usage=slow_usage,
+                promotions=promo_t, demotions=demo_t,
+                throughput=thru, latency=lat,
+                promo_scale=new_state.promo_scale,
+                thrash_events=counters.thrash_events,
+                fast_free=n_fast - fast_usage.sum(),
+                attempted_promotions=cand_t,
+                pool_free=provider.pool_free(owner, tier))
         return new_state, out
 
     return tick
