@@ -80,6 +80,38 @@ def test_select_rows_contiguous_bit_exact(T, seed):
                                   masks.astype(np.int64) @ np.asarray(ref))
 
 
+ROW_ROSTERS = {                   # pages per tenant, in layout order
+    "equal": (5, 5, 5, 5),
+    "last_shorter": (6, 6, 6, 2),   # the last window runs past L
+    "last_longest": (3, 4, 2, 9),
+    "single": (7,),
+    "zero_pages": (4, 0, 6, 0),     # empty tenants, one of them last
+}
+ROW_DTYPES = {"f32": (np.float32, -np.inf), "int32": (np.int32, 0),
+              "bool": (np.bool_, False)}
+
+
+@pytest.mark.parametrize("dtype", sorted(ROW_DTYPES))
+@pytest.mark.parametrize("roster", sorted(ROW_ROSTERS))
+def test_padded_rows_match_element_gather(roster, dtype):
+    """The window-copy row builder equals the per-element gather through
+    ``row_page`` with the ``row_valid`` fill, bit for bit."""
+    counts = ROW_ROSTERS[roster]
+    owner = np.repeat(np.arange(len(counts)), counts).astype(np.int32)
+    layout = S.plan_layout(owner, len(counts))
+    assert layout is not None
+    np_dtype, fill = ROW_DTYPES[dtype]
+    rng = np.random.default_rng(len(owner))
+    x = (rng.random(owner.shape[0]) < 0.5 if np_dtype is np.bool_
+         else rng.standard_normal(owner.shape[0]) * 100).astype(np_dtype)
+    xj = jnp.asarray(x)
+    got = np.asarray(S._padded_rows(xj, layout, fill))
+    want = np.asarray(jnp.where(layout.row_valid, xj[layout.row_page],
+                                jnp.asarray(fill, xj.dtype)))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
 def test_plan_layout_rejects_non_contiguous():
     assert S.plan_layout(np.array([0, 1, 0, 1], np.int32), 2) is None
     assert S.plan_layout(np.array([1, 1, 0, 0], np.int32), 2) is None

@@ -10,8 +10,11 @@ or a time.
 The topology is described inside a module-scoped fixture, never at import:
 only the process that runs these tests loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -92,15 +95,53 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in _compile(fn, shapes, one_chip)
 
 
-@pytest.mark.parametrize("impl", ["batched", "pallas"])
-def test_tick_compiles_for_v5e(one_chip, impl):
-    """One whole equilibria tick at the scale point (benchmarks/scale_sweep)."""
+@pytest.fixture(scope="module")
+def tick_hlo(one_chip):
+    """Compiled HLO text of one equilibria tick at the scale point
+    (benchmarks/scale_sweep), by impl; each impl compiles once."""
     from benchmarks.scale_sweep import scale_point
     from repro.core.engine import make_tick
     from repro.core.state import init_state
     cfg, owner = scale_point(T, L)
-    tick = make_tick(cfg, owner, "equilibria", k_max=K, impl=impl)
-    state = jax.eval_shape(lambda: init_state(cfg, L, owner=owner))
-    inputs = (_sds((L,), jnp.float32), _sds((L,), jnp.bool_))
-    text = _compile(tick, (state, inputs), one_chip)
-    assert ("tpu_custom_call" in text) == (impl == "pallas")
+    texts = {}
+
+    def get(impl):
+        if impl not in texts:
+            tick = make_tick(cfg, owner, "equilibria", k_max=K, impl=impl)
+            state = jax.eval_shape(lambda: init_state(cfg, L, owner=owner))
+            inputs = (_sds((L,), jnp.float32), _sds((L,), jnp.bool_))
+            texts[impl] = _compile(tick, (state, inputs), one_chip)
+        return texts[impl]
+    return get
+
+
+@pytest.mark.parametrize("impl", ["batched", "pallas"])
+def test_tick_compiles_for_v5e(tick_hlo, impl):
+    """One whole equilibria tick at the scale point."""
+    assert ("tpu_custom_call" in tick_hlo(impl)) == (impl == "pallas")
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]")
+_HLO_GATHER = re.compile(r" gather\(%([^,)\s]+).*slice_sizes=\{([\d,]*)\}")
+
+
+def _elements(dims: str) -> int:
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def test_static_tick_builds_rows_without_element_gather(tick_hlo):
+    """The static tick's padded [T, S] tenant rows are window copies: no
+    gather is left that reads an [L] page vector one element at a time
+    into T*S lanes (the per-element row gather runs at a few cycles per
+    element on the TPU)."""
+    text = tick_hlo("batched")
+    shapes = {m.group(1): m.group(2) for m in map(_HLO_DEF.match,
+                                                  text.splitlines()) if m}
+    S = L // T                      # the scale point's equal tenants
+    element_gathers = []
+    for line in text.splitlines():
+        g, d = _HLO_GATHER.search(line), _HLO_DEF.match(line)
+        if g and d and (_elements(shapes[g.group(1)]), _elements(d.group(2)),
+                        g.group(2)) == (L, T * S, "1"):
+            element_gathers.append(line.strip()[:200])
+    assert not element_gathers, element_gathers
