@@ -71,7 +71,9 @@ MODES = ("equilibria", "tpp", "memtis", "static")
 # compiled op reads ``.../tick/<stage>/...`` and a device trace's own time
 # splits by stage. ``select`` and ``commit`` nest a site scope (``demote``,
 # ``promote``, ``sync_demote``), the dynamic provider's ``ownership`` one
-# per lifecycle block.
+# per lifecycle block, ``telemetry`` ``detect`` and ``attrib`` for the
+# streaming detectors (step 9b) and the attribution ledger (step 9c) when
+# the tick carries them.
 STAGES = ("ownership", "alloc", "hotness", "regulate", "select", "commit",
           "telemetry", "control")
 
@@ -707,7 +709,7 @@ def make_tick_core(cfg: TieringConfig, provider: OwnershipProvider,
         # fed the exact per-tick values the offline detectors read from
         # TickOutput traces, so the streamed verdicts can agree bit-for-bit
         if detector is not None:
-            with jax.named_scope("telemetry"):
+            with jax.named_scope("telemetry"), jax.named_scope("detect"):
                 new_state = new_state._replace(det=DS.update_detector(
                     detector, state.det,
                     DS.DetectorSignals(
@@ -722,7 +724,7 @@ def make_tick_core(cfg: TieringConfig, provider: OwnershipProvider,
         # bit-exact because cand_t / promo_t / freed_t are the SAME values
         # step 7 accumulates into attempted/promotions/reclaims
         if attrib is not None:
-            with jax.named_scope("telemetry"):
+            with jax.named_scope("telemetry"), jax.named_scope("attrib"):
                 new_state = new_state._replace(attrib=AT.update_attribution(
                     attrib, state.attrib,
                     AT.AttribSignals(

@@ -13,7 +13,9 @@ degenerate schedule with constant ``want``). Three execution surfaces:
                          cheapest path when no host churns.
   ``run_mixed_fleet``  — heterogeneous static+churn hosts under one vmap,
                          full per-tick telemetry + pathology detection.
-  ``fleet_rollout``    — the long-horizon engine: chunked ``lax.scan``
+  ``FleetRollout``     — the long-horizon engine, built once and advanced
+                         in place (``fleet_rollout`` runs one horizon
+                         through it): chunked ``lax.scan``
                          rollouts with donated carries (no host round-trips
                          inside a chunk, O(chunk) not O(horizon) output
                          memory), schedule archetypes gathered in-graph
@@ -30,6 +32,7 @@ latency percentiles, migration rates, pathology counts from
 """
 from __future__ import annotations
 
+import copy
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -37,6 +40,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import TieringConfig
 from repro.core.churn import ChurnSchedule, make_churn_tick
@@ -370,6 +374,10 @@ def make_fleet_chunk(vtick, want_j: jax.Array, rates_j: jax.Array,
     rule flags exactly that regression); the int32 carry is exact up to
     2^31 per chunk and is widened to int64 host-side (``CounterLedger`` /
     ``absorb``).
+
+    Its own ops run under ``fleet/schedule`` (the per-host gather) and
+    ``fleet/fold`` (the sums); the tick's keep ``tick/<stage>`` below
+    ``fleet``.
     """
     def chunk_fn(states, arch, t0):
         zero_f = jnp.zeros(arch.shape, jnp.float32)
@@ -377,20 +385,24 @@ def make_fleet_chunk(vtick, want_j: jax.Array, rates_j: jax.Array,
 
         def body(carry, i):
             st, lat, thr, mig = carry
-            tm = jnp.mod(t0 + i, period)
-            w = jax.lax.dynamic_index_in_dim(want_j, tm, axis=1,
-                                             keepdims=False)
-            r = jax.lax.dynamic_index_in_dim(rates_j, tm, axis=1,
-                                             keepdims=False)
-            st, out = vtick(st, (r[arch], w[arch]))
-            lat = lat + out.latency.mean(axis=-1)
-            thr = thr + out.throughput.sum(axis=-1)
-            mig = mig + (out.promotions + out.demotions).sum(axis=-1)
+            with jax.named_scope("schedule"):
+                tm = jnp.mod(t0 + i, period)
+                w = jax.lax.dynamic_index_in_dim(want_j, tm, axis=1,
+                                                 keepdims=False)
+                r = jax.lax.dynamic_index_in_dim(rates_j, tm, axis=1,
+                                                 keepdims=False)
+                inp = (r[arch], w[arch])
+            st, out = vtick(st, inp)
+            with jax.named_scope("fold"):
+                lat = lat + out.latency.mean(axis=-1)
+                thr = thr + out.throughput.sum(axis=-1)
+                mig = mig + (out.promotions + out.demotions).sum(axis=-1)
             return (st, lat, thr, mig), None
 
-        (states, lat, thr, mig), _ = jax.lax.scan(
-            body, (states, zero_f, zero_f, zero_i),
-            jnp.arange(n, dtype=jnp.int32))
+        with jax.named_scope("fleet"):
+            (states, lat, thr, mig), _ = jax.lax.scan(
+                body, (states, zero_f, zero_f, zero_i),
+                jnp.arange(n, dtype=jnp.int32))
         return states, (lat, thr, mig)
     return chunk_fn
 
@@ -573,31 +585,33 @@ class RolloutSummary:
         }
 
 
-def fleet_rollout(cfg: TieringConfig, want: np.ndarray, rates: np.ndarray,
-                  ticks: int, *, host_arch: Optional[np.ndarray] = None,
-                  mode: str = "equilibria", k_max: int = 64,
-                  chunk: int = 256, n_pages: Optional[int] = None,
-                  shard: bool = True, warmup: bool = False,
-                  detect: bool = True, attrib: bool = True) -> RolloutSummary:
-    """Advance a fleet over a long horizon without host round-trips or
-    memory blowup.
+class FleetRollout:
+    """A fleet built once and advanced in place: the long-horizon engine.
 
     want [A, P, T] / rates [A, P, T, S] are schedule *archetypes* over a
     period P; ``host_arch`` [H] maps each host to its archetype (default:
     one host per archetype). The schedule is tiled in time (tick t reads
     column ``t % P``) and gathered per host in-graph, so H hosts over a
     10k-tick horizon cost O(A * P) schedule memory, not O(H * ticks).
+    ``horizon`` is the run's planned length, the window geometry of the
+    streaming detectors.
 
-    Execution is chunked: one jitted ``lax.scan`` of ``chunk`` ticks with
-    the fleet state donated between chunks (XLA reuses the carry buffers;
-    per-tick outputs are reduced inside the scan to [H] running sums).
-    With more than one local device and ``shard=True``, chunks run under
-    ``pmap`` with hosts sharded across devices; H must then divide over the
-    device count (ValueError otherwise — ``shard=False`` runs on one).
+    The constructor builds the specs, the vmapped churn tick and the fleet
+    state once; the chunk programs (one jitted ``lax.scan`` per chunk
+    length, the fleet state donated between chunks, per-tick outputs
+    reduced to [H] running sums inside the scan) compile on first use or
+    in ``warmup``. With more than one local device and ``shard=True``,
+    chunks run under ``pmap`` with hosts sharded across devices, reshaped
+    to [D, H/D]; H must then divide over the device count (ValueError
+    otherwise — ``shard=False`` runs on one). The schedule is an argument
+    of the chunk program, placed on every device once, never a constant
+    compiled into it.
 
-    ``warmup=True`` runs one throwaway chunk on a scratch fleet state
-    before the timed rollout so ``elapsed_s`` measures steady-state
-    execution, not XLA compilation (the benchmark gate's tick-rate).
+    ``advance(n)`` runs chunks of ``chunk`` ticks, the last one shorter
+    when ``chunk`` does not divide ``n``; each is ``run`` (dispatch) and
+    ``collect`` (the chunk's sums into the running totals, the cumulative
+    counters into the int64 ``CounterLedger``). ``resume`` continues the
+    fleet from a state it reached elsewhere.
 
     ``detect=True`` (default) carries the streaming pathology detectors
     (obs/streaming.py) in the fleet state: per-host per-tenant flag counters
@@ -611,122 +625,229 @@ def fleet_rollout(cfg: TieringConfig, want: np.ndarray, rates: np.ndarray,
     again O(H * T) state, so fleet attribution percentiles come out of a
     10k-tick rollout in O(1) output memory (``attribution_rollup``).
     """
-    want = np.asarray(want)
-    rates = np.asarray(rates)
-    A, period, T = want.shape
-    host_arch = np.arange(A) if host_arch is None else np.asarray(host_arch)
-    if host_arch.size and (host_arch.min() < 0 or host_arch.max() >= A):
-        # XLA gathers clamp out-of-range indices silently — fail loudly here
-        raise ValueError(f"host_arch must map into [0, {A}) archetypes")
-    H = host_arch.shape[0]
-    L = n_pages if n_pages is not None else \
-        cfg.n_fast_pages + cfg.n_slow_pages
-    cfg = cfg.with_(n_tenants=T)
-    det_spec = (make_detector(ticks, T, cfg.lower_protection)
-                if detect else None)
-    att_spec = make_attribution(T, cfg.lat_fast) if attrib else None
-    tick = make_churn_tick(cfg, L, mode=mode, k_max=k_max, detector=det_spec,
-                           attrib=att_spec)
-    vtick = jax.vmap(tick)
-    want_j = jnp.asarray(want, jnp.int32)
-    rates_j = jnp.asarray(rates, jnp.float32)
 
-    def make_chunk_fn(n: int):
-        return make_fleet_chunk(vtick, want_j, rates_j, period, n)
+    AXIS = "chips"     # the pmap axis: hosts sharded over local devices
 
-    chunk = max(min(chunk, ticks), 1)
-    D = jax.local_device_count()
-    use_pmap = bool(shard) and D > 1
-    if use_pmap and H % D:
-        raise ValueError(f"fleet_rollout shards hosts across devices: "
-                         f"H={H} hosts do not divide over D={D} devices; "
-                         f"pass shard=False to run on one device")
-    states = stack_states(init_state(cfg, L, detector=det_spec,
-                                     attrib=att_spec), H)
-    if use_pmap:
-        def resh(x):
-            return jnp.reshape(x, (D, H // D) + x.shape[1:])
-        states = jax.tree_util.tree_map(resh, states)
-        arch = jnp.asarray(host_arch.reshape(D, H // D))
+    def __init__(self, cfg: TieringConfig, want: np.ndarray,
+                 rates: np.ndarray, horizon: int, *,
+                 host_arch: Optional[np.ndarray] = None,
+                 mode: str = "equilibria", k_max: int = 64,
+                 chunk: int = 256, n_pages: Optional[int] = None,
+                 shard: bool = True, detect: bool = True,
+                 attrib: bool = True):
+        want = np.asarray(want)
+        rates = np.asarray(rates)
+        A, self.period, T = want.shape
+        host_arch = (np.arange(A) if host_arch is None
+                     else np.asarray(host_arch))
+        if host_arch.size and (host_arch.min() < 0 or host_arch.max() >= A):
+            # XLA gathers clamp out-of-range indices silently — fail loudly
+            raise ValueError(f"host_arch must map into [0, {A}) archetypes")
+        H = self.n_hosts = host_arch.shape[0]
+        L = self.n_pages = (n_pages if n_pages is not None
+                            else cfg.n_fast_pages + cfg.n_slow_pages)
+        cfg = self.cfg = cfg.with_(n_tenants=T)
+        self.detector = (make_detector(horizon, T, cfg.lower_protection)
+                         if detect else None)
+        self.attribution = make_attribution(T, cfg.lat_fast) \
+            if attrib else None
+        tick = make_churn_tick(cfg, L, mode=mode, k_max=k_max,
+                               detector=self.detector,
+                               attrib=self.attribution)
 
-        def compile_chunk(n):
-            return jax.pmap(make_chunk_fn(n), in_axes=(0, 0, None),
-                            donate_argnums=(0,))
-    else:
-        arch = jnp.asarray(host_arch)
+        def host_tick(state, inputs):
+            # the vmap's own name wraps this scope, not the tick's: ops keep
+            # ``tick/<stage>`` in their names, as on a single host
+            with jax.named_scope("hosts"):
+                return tick(state, inputs)
+        self._vtick = jax.vmap(host_tick)
+        self.chunk = max(min(chunk, horizon), 1)
+        D = jax.local_device_count()
+        self.sharded = bool(shard) and D > 1
+        if self.sharded and H % D:
+            raise ValueError(f"fleet_rollout shards hosts across devices: "
+                             f"H={H} hosts do not divide over D={D} devices; "
+                             f"pass shard=False to run on one device")
+        self.n_devices = D if self.sharded else 1
+        want_i = want.astype(np.int32)
+        rates_f = rates.astype(np.float32)
+        host_arch = host_arch.astype(np.int32)
+        if self.sharded:
+            # [D, ...] arrays: one slice on each device, as pmap takes them
+            self._on_each = NamedSharding(
+                Mesh(np.array(jax.local_devices()), (self.AXIS,)),
+                PartitionSpec(self.AXIS))
 
-        def compile_chunk(n):
-            return jax.jit(make_chunk_fn(n), donate_argnums=(0,))
-
-    run_chunk = compile_chunk(chunk)
-    n_full, rem = divmod(ticks, chunk)
-    run_rem = compile_chunk(rem) if rem else None
-
-    if warmup:
-        # compile (and once-run) every chunk program on a scratch state —
-        # donation consumes the scratch buffers, the real fleet is untouched
-        scratch = stack_states(init_state(cfg, L, detector=det_spec,
-                                          attrib=att_spec), H)
-        if use_pmap:
-            scratch = jax.tree_util.tree_map(resh, scratch)
-        scratch, _ = run_chunk(scratch, arch, 0)
-        if run_rem is not None:
-            jax.block_until_ready(
-                jax.tree_util.tree_leaves(run_rem(scratch, arch, 0)[0])[0])
+            def each(x):
+                return jax.device_put(x, self._on_each)
+            self.schedule = tuple(each(np.broadcast_to(x, (D,) + x.shape))
+                                for x in (want_i, rates_f))
+            self.arch = each(host_arch.reshape(D, H // D))
         else:
-            jax.block_until_ready(jax.tree_util.tree_leaves(scratch)[0])
+            self.schedule = (jnp.asarray(want_i), jnp.asarray(rates_f))
+            self.arch = jnp.asarray(host_arch)
+        self._programs: Dict[int, object] = {}
+        self.elapsed_s = 0.0         # wall time inside ``advance``
+        self.last_sums: Optional[Tuple[np.ndarray, ...]] = None
+        self._begin(self.fresh_states(), 0)
 
-    lat_sum = np.zeros(H, np.float64)
-    thr_sum = np.zeros(H, np.float64)
-    mig_sum = np.zeros(H, np.int64)
+    # ------------------------------------------------------------ set-up --
+    def fresh_states(self):
+        """The fleet's starting state: every host an all-free pool, laid
+        out [D, H/D, ...] when sharded."""
+        one = init_state(self.cfg, self.n_pages, detector=self.detector,
+                         attrib=self.attribution)
+        if not self.sharded:
+            return stack_states(one, self.n_hosts)
+        # built on the host and sent slice by slice: no device ever holds
+        # more than its own hosts
+        return self._place(jax.tree_util.tree_map(
+            lambda x: np.broadcast_to(np.asarray(x), (self.n_hosts,)
+                                      + x.shape), one))
 
-    def host_view(tree):
+    def resume(self, states, t: int) -> None:
+        """Continue from ``states``, host arrays [H, ...] of the fleet as
+        it stood after ``t`` ticks (a checkpoint, or the same hosts run
+        elsewhere): the next tick is ``t``, and the sums, the ledger and
+        ``summary`` count from here."""
+        self._begin(self._place(states), t)
+
+    def _place(self, tree):
+        """Host arrays [H, ...] laid out as the chunk program takes them."""
+        if not self.sharded:
+            return jax.tree_util.tree_map(jnp.asarray, tree)
+        D, H = self.n_devices, self.n_hosts
+        return jax.device_put(jax.tree_util.tree_map(
+            lambda x: np.reshape(x, (D, H // D) + np.shape(x)[1:]), tree),
+            self._on_each)
+
+    def _begin(self, states, t: int) -> None:
+        self.states = states
+        self.t = self.t0 = t         # the fleet's clock, and where it began
+        self._lat = np.zeros(self.n_hosts, np.float64)
+        self._thr = np.zeros(self.n_hosts, np.float64)
+        self._mig = np.zeros(self.n_hosts, np.int64)
+        self.ledger = CounterLedger(self._ledger_view(states))
+
+    def chunk_fn(self, n: int):
+        """The chunk program of ``n`` ticks before ``pmap``/``jit``:
+        (states, arch, t0, want, rates) -> (states, sums)."""
+        vtick, period = self._vtick, self.period
+
+        def run(states, arch, t0, want, rates):
+            return make_fleet_chunk(vtick, want, rates, period, n)(
+                states, arch, t0)
+        return run
+
+    def program(self, n: int):
+        """The compiled chunk program of ``n`` ticks (built once)."""
+        if n not in self._programs:
+            fn = self.chunk_fn(n)
+            self._programs[n] = (
+                jax.pmap(fn, axis_name=self.AXIS,
+                         in_axes=(0, 0, None, 0, 0), donate_argnums=(0,))
+                if self.sharded else jax.jit(fn, donate_argnums=(0,)))
+        return self._programs[n]
+
+    def warmup(self, *lengths: int) -> None:
+        """Compile (and once-run) the chunk programs of these lengths on a
+        scratch fleet state — donation consumes the scratch buffers, the
+        real fleet is untouched."""
+        scratch = self.fresh_states()
+        for n in lengths:
+            scratch, _ = self.program(n)(scratch, self.arch, 0,
+                                         *self.schedule)
+        jax.block_until_ready(jax.tree_util.tree_leaves(scratch)[0])
+
+    # ----------------------------------------------------------- advance --
+    def advance(self, n_ticks: int) -> None:
+        """Run ``n_ticks`` more ticks, chunk by chunk, absorbing each."""
+        t_wall = time.perf_counter()
+        end = self.t + n_ticks
+        while self.t < end:
+            self.run(min(self.chunk, end - self.t))
+            self.collect()
+        jax.block_until_ready(jax.tree_util.tree_leaves(self.states)[0])
+        self.elapsed_s += time.perf_counter() - t_wall
+
+    def run(self, n: int) -> None:
+        """Dispatch a chunk of ``n`` ticks; does not wait for the device."""
+        with jax.profiler.TraceAnnotation("fleet/chunk"):
+            self.states, self._chunk_sums = self.program(n)(
+                self.states, self.arch, self.t, *self.schedule)
+            self.t += n
+
+    def collect(self) -> None:
+        """Pull the chunk's sums into the totals and the cumulative
+        counters into the ledger."""
+        with jax.profiler.TraceAnnotation("fleet/ledger"):
+            self.last_sums = lat, thr, mig = tuple(
+                np.asarray(a).reshape(self.n_hosts)
+                for a in self._chunk_sums)
+            self._lat = self._lat + lat
+            self._thr = self._thr + thr
+            # the chunk's int32 migration count, widened wrap-safe like the
+            # cumulative counters (exact while one chunk migrates < 2^32
+            # pages)
+            self._mig = self._mig + (mig.astype(np.int64) % _WRAP32)
+            self.ledger.absorb(self._ledger_view(self.states))
+
+    def host_view(self, tree):
         """Pull a device subtree to host with a flat [H, ...] host axis."""
+        H = self.n_hosts
         return jax.tree_util.tree_map(
             lambda x: np.asarray(x).reshape((H,) + np.shape(x)[2:])
-            if use_pmap else np.asarray(x), tree)
+            if self.sharded else np.asarray(x), tree)
 
-    def ledger_view(st):
+    def _ledger_view(self, st):
         tree = {"counters": st.counters}
-        if att_spec is not None:
+        if self.attribution is not None:
             tree["att"] = {"comp": st.attrib.comp, "total": st.attrib.total,
                            "sketch": st.attrib.sketch}
-        return host_view(tree)
+        return self.host_view(tree)
 
-    ledger = CounterLedger(ledger_view(states))
+    # ----------------------------------------------------------- results --
+    def summary(self) -> RolloutSummary:
+        """The rollout since it began (or was resumed)."""
+        states = self.states
+        if self.sharded:
+            H = self.n_hosts
+            states = jax.tree_util.tree_map(
+                lambda x: jnp.reshape(x, (H,) + x.shape[2:]), states)
+        ticks = self.t - self.t0
+        per = max(ticks, 1)
+        return RolloutSummary(
+            n_hosts=self.n_hosts, ticks=ticks, chunk=self.chunk,
+            sharded=self.sharded, elapsed_s=self.elapsed_s,
+            latency_mean=self._lat / per, throughput_mean=self._thr / per,
+            migrations_per_tick=self._mig / per,
+            final_state=states, detector=self.detector,
+            attribution=self.attribution, ledger=copy.copy(self.ledger))
 
-    def absorb(acc):
-        nonlocal lat_sum, thr_sum, mig_sum
-        lat, thr, mig = (np.asarray(a).reshape(H) for a in acc)
-        lat_sum = lat_sum + lat
-        thr_sum = thr_sum + thr
-        # the chunk's int32 migration count, widened wrap-safe like the
-        # cumulative counters (exact while one chunk migrates < 2^32 pages)
-        mig_sum = mig_sum + (mig.astype(np.int64) % _WRAP32)
 
-    t0_wall = time.perf_counter()
-    t = 0
-    for _ in range(n_full):
-        states, acc = run_chunk(states, arch, t)
-        absorb(acc)
-        ledger.absorb(ledger_view(states))
-        t += chunk
-    if run_rem is not None:
-        states, acc = run_rem(states, arch, t)
-        absorb(acc)
-        ledger.absorb(ledger_view(states))
-        t += rem
-    jax.block_until_ready(jax.tree_util.tree_leaves(states)[0])
-    elapsed = time.perf_counter() - t0_wall
+def fleet_rollout(cfg: TieringConfig, want: np.ndarray, rates: np.ndarray,
+                  ticks: int, *, host_arch: Optional[np.ndarray] = None,
+                  mode: str = "equilibria", k_max: int = 64,
+                  chunk: int = 256, n_pages: Optional[int] = None,
+                  shard: bool = True, warmup: bool = False,
+                  detect: bool = True, attrib: bool = True) -> RolloutSummary:
+    """Advance a fleet over a long horizon without host round-trips or
+    memory blowup: a ``FleetRollout`` of horizon ``ticks``, advanced
+    ``ticks`` ticks in chunks of ``chunk`` (the last one shorter when
+    ``chunk`` does not divide ``ticks``).
 
-    if use_pmap:
-        states = jax.tree_util.tree_map(
-            lambda x: jnp.reshape(x, (H,) + x.shape[2:]), states)
-    return RolloutSummary(
-        n_hosts=H, ticks=ticks, chunk=chunk, sharded=use_pmap,
-        elapsed_s=elapsed,
-        latency_mean=lat_sum / ticks,
-        throughput_mean=thr_sum / ticks,
-        migrations_per_tick=mig_sum / ticks,
-        final_state=states, detector=det_spec, attribution=att_spec,
-        ledger=ledger)
+    ``warmup=True`` runs every chunk program once on a scratch fleet state
+    before the timed rollout so ``elapsed_s`` measures steady-state
+    execution, not XLA compilation (the benchmark gate's tick-rate).
+    See ``FleetRollout`` for the schedule, sharding, detector and
+    attribution arguments.
+    """
+    fleet = FleetRollout(cfg, want, rates, ticks, host_arch=host_arch,
+                         mode=mode, k_max=k_max, chunk=chunk,
+                         n_pages=n_pages, shard=shard, detect=detect,
+                         attrib=attrib)
+    if warmup:
+        rem = ticks % fleet.chunk
+        fleet.warmup(*((fleet.chunk, rem) if rem else (fleet.chunk,)))
+    fleet.advance(ticks)
+    return fleet.summary()

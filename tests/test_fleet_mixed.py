@@ -23,7 +23,7 @@ from repro.core.churn import make_churn_tick
 from repro.core.state import init_state, stack_states
 from repro.core.workloads import (ChurnSlot, build_churn_schedule,
                                   cache_like, spark_like, thrasher, web_like)
-from repro.obs.fleet import (fleet_rollout, mixed_fleet_hosts,
+from repro.obs.fleet import (FleetRollout, fleet_rollout, mixed_fleet_hosts,
                              run_mixed_fleet, stack_schedules)
 
 _TICKS = 160
@@ -166,6 +166,63 @@ def test_chunked_rollout_matches_single_scan():
                                rtol=1e-6)
     np.testing.assert_allclose(runs[0].migrations_per_tick,
                                runs[1].migrations_per_tick, rtol=1e-6)
+
+
+def test_rollout_advanced_in_pieces_matches_one_call():
+    """A FleetRollout advanced in uneven pieces that end on chunk
+    boundaries (7 + 14 + 3 ticks in chunks of 7) runs the same chunks as one
+    fleet_rollout call: the same RolloutSummary and final state, bit for
+    bit."""
+    cfg = _cfg()
+    hosts = _hosts()
+    ticks = 24
+    want, rates = stack_schedules(
+        [build_churn_schedule(s, ticks) for s in hosts])
+    whole = fleet_rollout(cfg, want, rates, ticks, chunk=7, k_max=32)
+    fleet = FleetRollout(cfg, want, rates, ticks, chunk=7, k_max=32)
+    for n in (7, 14, 3):
+        fleet.advance(n)
+    pieces = fleet.summary()
+    assert (pieces.n_hosts, pieces.ticks, pieces.chunk, pieces.sharded) == \
+        (whole.n_hosts, whole.ticks, whole.chunk, whole.sharded)
+    for name in ("latency_mean", "throughput_mean", "migrations_per_tick"):
+        np.testing.assert_array_equal(getattr(pieces, name),
+                                      getattr(whole, name), err_msg=name)
+    for a, b in zip(jax.tree_util.tree_leaves(pieces.final_state),
+                    jax.tree_util.tree_leaves(whole.final_state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree_util.tree_leaves(pieces.ledger.total),
+                    jax.tree_util.tree_leaves(whole.ledger.total)):
+        np.testing.assert_array_equal(a, b)
+    assert pieces.attribution_rollup() == whole.attribution_rollup()
+    assert pieces.pathology_rollup() == whole.pathology_rollup()
+
+
+def test_resumed_rollout_continues_the_fleet():
+    """A FleetRollout resumed from another's state after 14 ticks and
+    advanced 10 more ends where 24 ticks of one rollout end; the two
+    ledgers add up to the one."""
+    cfg = _cfg()
+    hosts = _hosts()
+    ticks = 24
+    want, rates = stack_schedules(
+        [build_churn_schedule(s, ticks) for s in hosts])
+    whole = FleetRollout(cfg, want, rates, ticks, chunk=7, k_max=32)
+    whole.advance(ticks)
+    first = FleetRollout(cfg, want, rates, ticks, chunk=7, k_max=32)
+    first.advance(14)
+    rest = FleetRollout(cfg, want, rates, ticks, chunk=7, k_max=32)
+    rest.resume(first.host_view(first.states), 14)
+    rest.advance(ticks - 14)
+    done = rest.summary()
+    assert (done.ticks, rest.t) == (ticks - 14, ticks)
+    for a, b in zip(jax.tree_util.tree_leaves(done.final_state),
+                    jax.tree_util.tree_leaves(whole.summary().final_state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b, c in zip(jax.tree_util.tree_leaves(first.ledger.total),
+                       jax.tree_util.tree_leaves(done.ledger.total),
+                       jax.tree_util.tree_leaves(whole.ledger.total)):
+        np.testing.assert_array_equal(a + b, c)
 
 
 def test_rollout_archetype_tiling_matches_explicit_hosts():

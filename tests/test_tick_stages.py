@@ -27,6 +27,7 @@ OP_NAME = re.compile(r'op_name="([^"]*)"')
 SITES = {"select": {"demote", "promote", "sync_demote"},
          "commit": {"demote", "promote", "sync_demote"}}
 LIFECYCLE = {"reclaim", "grant", "slot_reuse", "schedule", "repartition"}
+FOLDS = {"detect", "attrib"}        # steps 9b and 9c, under telemetry
 
 
 @pytest.fixture(scope="module")
@@ -95,6 +96,43 @@ def test_every_stage_appears(hlo):
     lifecycle = {s[1] for s in _scopes(hlo["dynamic"])
                  if s[0] == "ownership" and len(s) > 1}
     assert lifecycle == LIFECYCLE
+
+
+def test_fleet_folds_have_their_own_scopes():
+    """A churn tick built with the streaming detectors and the attribution
+    ledger runs them under ``telemetry/detect`` and ``telemetry/attrib``;
+    built without them, it has the op set it had before: no such scope."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.obs.attribution import make_attribution
+    from repro.obs.streaming import make_detector
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        cfg = TieringConfig(n_tenants=T, n_fast_pages=96, n_slow_pages=L,
+                            lower_protection=(8,) * T,
+                            upper_bound=(0, 40, 40, 40))
+        det = make_detector(64, T, cfg.lower_protection)
+        att = make_attribution(T, cfg.lat_fast)
+        tick = make_churn_tick(cfg, L, mode="equilibria", k_max=K,
+                               impl="batched", detector=det, attrib=att)
+        text = jax.jit(tick).lower(
+            init_state(cfg, L, detector=det, attrib=att),
+            (jnp.ones((T, S), jnp.float32), jnp.full((T,), 40, jnp.int32))
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    scopes = _scopes(text)
+    assert all(s and s[0] in STAGES for s in scopes)
+    folds = {s[:2] for s in scopes if len(s) > 1 and s[1] in FOLDS}
+    assert folds == {("telemetry", f) for f in FOLDS}
+
+
+def test_ticks_without_the_folds_have_no_fold_scopes(hlo):
+    for text in hlo.values():
+        assert not {s[1] for s in _scopes(text) if len(s) > 1} & FOLDS
 
 
 def test_bench_stage_metrics_name_the_stages():
