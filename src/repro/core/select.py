@@ -18,12 +18,13 @@ Two batched strategies, chosen at trace time from the static owner vector:
   v5e the [64, 4120] row build of a 262,120-page vector takes 64-72 us as
   window copies and 1.8-2.0 ms as a per-element gather.
 * **generic fallback** (arbitrary owner permutation): one stable
-  lexicographic sort by (segment, key) — `segment_ranks` — and scatter-add
-  reductions. Still constant in T. Because the owner vector enters as a
-  runtime array (never a trace constant), this is also the path the
-  dynamic-ownership engine (core/churn.py) routes every churned layout
-  through: the same compiled sort serves any ownership the lifecycle events
-  produce.
+  lexicographic sort by (segment, key) — `segment_ranks` — and per-bucket
+  sums and lookups (`bucket_sum`, `bucket_lookup`: compare-and-reduce over
+  [T+1, L] on the TPU, scatter-add and gather on the CPU). Still constant
+  in T. Because the owner vector enters as a runtime array (never a trace
+  constant), this is also the path the dynamic-ownership engine
+  (core/churn.py) routes every churned layout through: the same compiled
+  sort serves any ownership the lifecycle events produce.
 
 Tie-breaking matches `jax.lax.top_k` exactly in both strategies ("lower
 index wins" on equal scores), so results are bit-equal to the unrolled
@@ -161,12 +162,12 @@ def segment_ranks(seg: jax.Array, key: jax.Array, n_seg: int) -> jax.Array:
     number of segments.
     """
     L = seg.shape[0]
+    seg = seg.astype(jnp.int32)
     idx = jnp.arange(L, dtype=jnp.int32)
-    seg_s, _, idx_s = jax.lax.sort((seg.astype(jnp.int32), key, idx),
-                                   num_keys=2)
-    counts = jnp.zeros((n_seg + 1,), jnp.int32).at[seg].add(1)
+    seg_s, _, idx_s = jax.lax.sort((seg, key, idx), num_keys=2)
+    counts = bucket_sum(jnp.ones((L,), jnp.int32), seg, n_seg + 1)
     starts = jnp.cumsum(counts) - counts          # exclusive prefix sum
-    rank_s = jnp.arange(L, dtype=jnp.int32) - starts[seg_s]
+    rank_s = jnp.arange(L, dtype=jnp.int32) - bucket_lookup(starts, seg_s)
     return jnp.zeros((L,), jnp.int32).at[idx_s].set(rank_s)
 
 
@@ -183,7 +184,7 @@ def select_top_quota(score: jax.Array, owner: jax.Array, active: jax.Array,
     ranks = segment_ranks(seg, -score, n_tenants)
     q = jnp.minimum(quotas.astype(jnp.int32), min(k_cap, L))
     q_ext = jnp.concatenate([q, jnp.zeros((1,), jnp.int32)])
-    return active & (ranks < q_ext[seg])
+    return active & (ranks < bucket_lookup(q_ext, seg))
 
 
 def by_tenant_scatter(x: jax.Array, owner: jax.Array,
@@ -197,8 +198,65 @@ def by_tenant_pooled(x: jax.Array, owner: jax.Array,
     """Per-tenant sum tolerant of the free-pool sentinel ``owner ==
     n_tenants``: sentinel lanes land in a scratch bucket instead of being
     clipped onto the last real tenant (XLA's default scatter mode clips
-    out-of-bounds indices)."""
-    return jnp.zeros((n_tenants + 1,), x.dtype).at[owner].add(x)[:n_tenants]
+    out-of-bounds indices). Integer sums are ``bucket_sum``s; floats keep
+    the scatter-add, whose association the golden traces pin bitwise."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.zeros((n_tenants + 1,), x.dtype).at[owner].add(
+            x)[:n_tenants]
+    return bucket_sum(x, owner, n_tenants + 1)[:n_tenants]
+
+
+def _bucket_hits(idx: jax.Array, n: int) -> jax.Array:
+    """[n, L] bool, lane axis minor: lane l falls in bucket b."""
+    return idx[None, :] == jnp.arange(n, dtype=idx.dtype)[:, None]
+
+
+def _bucket_sum_dense(x: jax.Array, idx: jax.Array, n: int) -> jax.Array:
+    """``bucket_sum`` as one compare-and-reduce over [n, L]: the compare
+    and select fuse into the reduce's input, so no [n, L] array is
+    written."""
+    return jnp.where(_bucket_hits(idx, n), x[None, :], 0).sum(
+        axis=1, dtype=x.dtype)
+
+
+def _bucket_lookup_dense(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``bucket_lookup`` with no gather: each lane's bit pattern is the one
+    term of a compare-and-reduce over [n, L], so every dtype comes back
+    bit for bit (-0.0, infinities and NaN payloads included). Ids outside
+    [0, n) read 0."""
+    if table.dtype == jnp.bool_:
+        return _bucket_lookup_dense(table.astype(jnp.int32),
+                                    idx).astype(jnp.bool_)
+    bits = jnp.dtype(f"uint{8 * table.dtype.itemsize}")
+    tb = jax.lax.bitcast_convert_type(table, bits)
+    got = jnp.where(_bucket_hits(idx, table.shape[0]), tb[:, None],
+                    jnp.zeros((), bits)).sum(axis=0, dtype=bits)
+    return jax.lax.bitcast_convert_type(got, table.dtype)
+
+
+# XLA lowers a TPU scatter-add, or a gather from a small table, to a
+# near-serial loop over its L indices, while the dense forms above are
+# vector work there: over 65 buckets and 386,048 lanes on one TPU v5e the
+# sum takes 3,406 us as a scatter-add and 38 us dense, the lookup 3,096 us
+# as a gather and 56 us dense. On XLA's CPU the scatter and the gather are
+# the fast forms. All forms agree bit for bit, so the platform picks: the
+# choice is made when the program is lowered, and the compiler never sees
+# a conditional.
+def bucket_sum(x: jax.Array, idx: jax.Array, n: int) -> jax.Array:
+    """Per-bucket sum of an integer (or bool, counted as int32) [L] vector
+    over bucket ids ``idx`` in [0, n). Integer addition is associative, so
+    every form gives the same bits."""
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.int32)
+    return jax.lax.platform_dependent(
+        x, idx, cpu=lambda x, i: jnp.zeros((n,), x.dtype).at[i].add(x),
+        default=lambda x, i: _bucket_sum_dense(x, i, n))
+
+
+def bucket_lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for an [n] table and bucket ids ``idx`` in [0, n)."""
+    return jax.lax.platform_dependent(
+        table, idx, cpu=lambda t, i: t[i], default=_bucket_lookup_dense)
 
 
 def select_global(score: jax.Array, mask: jax.Array, quota: jax.Array,

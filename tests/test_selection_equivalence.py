@@ -3,6 +3,7 @@ engine impl="batched") is pinned bit-exactly to the seed's per-tenant
 unrolled loops (impl="unrolled") — randomized scores, quotas (zero, partial,
 over-supply), masks, and tie cases, for T in {1, 3, 8} — plus trace-time
 T-independence of the batched tick's jaxpr."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -188,3 +189,138 @@ def test_batched_tick_trace_is_T_independent():
     assert un_big.histogram().get("top_k", 0) > \
         un_small.histogram().get("top_k", 0)
     assert un_big.n_eqns > un_small.n_eqns
+
+
+# ---------------------------------------------- per-bucket dense forms ----
+# ``bucket_sum`` and ``bucket_lookup`` pick their form by platform: the CPU
+# runs the scatter-add and the gather, the TPU the dense compare-and-reduce.
+# These cases call the dense forms directly, so a CPU run checks the path
+# the chip takes against the CPU's.
+LB = 1000                         # not a multiple of 128
+
+
+def _owners(T, case, rng):
+    if case == "all_sentinel":
+        return np.full(LB, T, np.int32)
+    owner = rng.integers(0, T + 1, LB).astype(np.int32)   # T = pool sentinel
+    if case == "empty_tenants":
+        owner[owner % 2 == 1] = T        # odd tenants own nothing
+    return owner
+
+
+@pytest.mark.parametrize("case", ["random", "all_sentinel", "empty_tenants"])
+@pytest.mark.parametrize("dtype", ["bool", "int32"])
+@pytest.mark.parametrize("T", [1, 16, 64])
+def test_bucket_sum_dense_matches_scatter_add(T, dtype, case):
+    rng = np.random.default_rng(T)
+    owner = jnp.asarray(_owners(T, case, rng))
+    x = (rng.random(LB) < 0.5 if dtype == "bool"
+         else rng.integers(-2 ** 30, 2 ** 30, LB, dtype=np.int32))
+    xi = jnp.asarray(x).astype(jnp.int32)
+    want = np.asarray(jnp.zeros((T + 1,), jnp.int32).at[owner].add(xi))
+    dense = np.asarray(jax.jit(S._bucket_sum_dense, static_argnums=2)(
+        xi, owner, T + 1))
+    got = np.asarray(jax.jit(S.bucket_sum, static_argnums=2)(
+        jnp.asarray(x), owner, T + 1))
+    assert dense.dtype == got.dtype == np.int32
+    np.testing.assert_array_equal(dense, want)
+    np.testing.assert_array_equal(got, want)
+    if case != "random":
+        assert (want[:T][1::2] == 0).all()
+
+
+SPECIAL_F32 = np.array([-0.0, 0.0, np.inf, -np.inf, np.nan,
+                        np.frombuffer(np.uint32(0x7FC01234).tobytes(),
+                                      np.float32)[0]], np.float32)
+
+
+@pytest.mark.parametrize("dtype", ["int32", "float32", "bool"])
+@pytest.mark.parametrize("T", [1, 16, 64])
+def test_bucket_lookup_dense_is_bitwise(T, dtype):
+    rng = np.random.default_rng(100 + T)
+    idx = jnp.asarray(rng.integers(0, T + 1, LB).astype(np.int32))
+    if dtype == "float32":
+        table = rng.standard_normal(T + 1).astype(np.float32)
+        table[:len(SPECIAL_F32)] = SPECIAL_F32[:T + 1]
+    elif dtype == "int32":
+        table = rng.integers(-2 ** 31, 2 ** 31 - 1, T + 1, dtype=np.int32)
+    else:
+        table = rng.random(T + 1) < 0.5
+    tab = jnp.asarray(table)
+    want = np.asarray(tab[idx])
+    for fn in (S._bucket_lookup_dense, S.bucket_lookup):
+        got = np.asarray(jax.jit(fn)(tab, idx))
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+@pytest.fixture
+def dense_forms(monkeypatch):
+    """Route ``bucket_sum``/``bucket_lookup`` through the TPU's dense forms
+    on any platform; yields the names of the forms traced."""
+    traced = []
+
+    def bucket_sum(x, idx, n):
+        traced.append("sum")
+        x = x.astype(jnp.int32) if x.dtype == jnp.bool_ else x
+        return S._bucket_sum_dense(x, idx, n)
+
+    def bucket_lookup(table, idx):
+        traced.append("lookup")
+        return S._bucket_lookup_dense(table, idx)
+    monkeypatch.setattr(S, "bucket_sum", bucket_sum)
+    monkeypatch.setattr(S, "bucket_lookup", bucket_lookup)
+    return traced
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_segment_select_through_dense_forms(seed, request):
+    """``segment_ranks`` and ``select_top_quota`` give the same bits with
+    the dense forms as with the CPU's scatter and gather, on random owner
+    permutations with pool-sentinel lanes and tied scores."""
+    rng = np.random.default_rng(500 + seed)
+    T = int(rng.choice([1, 5, 16]))
+    owner = rng.integers(0, T + 1, L).astype(np.int32)
+    score = rng.integers(-3, 3, L).astype(np.float32)
+    active = rng.random(L) < 0.7
+    quotas = rng.integers(0, L // 2, T).astype(np.int32)
+    key = rng.integers(-4, 4, L).astype(np.int32)
+
+    def run():
+        ranks = S.segment_ranks(jnp.asarray(owner), jnp.asarray(key), T)
+        sel = S.select_top_quota(jnp.asarray(score), jnp.asarray(owner),
+                                 jnp.asarray(active), jnp.asarray(quotas),
+                                 T, 17)
+        sums = S.by_tenant_pooled(jnp.asarray(active).astype(jnp.int32),
+                                  jnp.asarray(owner), T)
+        return [np.asarray(a) for a in (ranks, sel, sums)]
+
+    base = run()
+    traced = request.getfixturevalue("dense_forms")
+    for a, b in zip(run(), base):
+        np.testing.assert_array_equal(a, b)
+    assert {"sum", "lookup"} <= set(traced)
+
+
+def test_churn_tick_through_dense_forms(request):
+    """A whole churned run with the TPU's dense forms equals the CPU's
+    scatter/gather run bit for bit: every state leaf and every output."""
+    from repro.core.churn import ChurnSchedule, run_churn_engine
+    cfg = TieringConfig(n_tenants=4, n_fast_pages=48, n_slow_pages=112,
+                        lower_protection=(12, 12, 0, 0),
+                        upper_bound=(0, 20, 0, 0))
+    rng = np.random.default_rng(7)
+    want = rng.integers(0, 30, (16, 4)).astype(np.int32)
+    want[rng.random(want.shape) < 0.3] = 0
+    rates = (rng.random((16, 4, 30)) * 5.0).astype(np.float32)
+    sched = ChurnSchedule(want=want, rates=rates)
+    base = run_churn_engine(cfg, sched, k_max=32)
+    traced = request.getfixturevalue("dense_forms")
+    dense = run_churn_engine(cfg, sched, k_max=32)
+    assert {"sum", "lookup"} <= set(traced)
+    flat_d, tree_d = jax.tree_util.tree_flatten(dense)
+    flat_b, tree_b = jax.tree_util.tree_flatten(base)
+    assert tree_d == tree_b
+    for a, b in zip(flat_d, flat_b):
+        np.testing.assert_array_equal(np.atleast_1d(a).view(np.uint8),
+                                      np.atleast_1d(b).view(np.uint8))

@@ -145,3 +145,58 @@ def test_static_tick_builds_rows_without_element_gather(tick_hlo):
                         g.group(2)) == (L, T * S, "1"):
             element_gathers.append(line.strip()[:200])
     assert not element_gathers, element_gathers
+
+
+_HLO_TYPED = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]")
+_HLO_INDEXED = re.compile(r" (scatter|gather)\(([^)]*)\)")
+
+
+@pytest.fixture(scope="module")
+def churn_tick_hlo(one_chip):
+    """Compiled HLO text of one dynamic-ownership (churn) tick: T=64 slots
+    over a pool of L pages, rates of L/T lanes a slot."""
+    from benchmarks.scale_sweep import scale_point
+    from repro.core.churn import make_churn_tick
+    from repro.core.state import init_state
+    cfg, _ = scale_point(T, L)
+    tick = make_churn_tick(cfg, L, "equilibria", k_max=K)
+    state = jax.eval_shape(lambda: init_state(cfg, L))
+    inputs = (_sds((T, L // T), jnp.float32), _sds((T,), jnp.int32))
+    return _compile(tick, (state, inputs), one_chip)
+
+
+def _page_axis_ops(text):
+    """(kind, output (dtype, elements), operands [(dtype, elements)]) of
+    every scatter and gather that indexes the page axis (L indices)."""
+    shapes = {m.group(1): (m.group(2), _elements(m.group(3)))
+              for m in map(_HLO_TYPED.match, text.splitlines()) if m}
+    found = []
+    for line in text.splitlines():
+        op, d = _HLO_INDEXED.search(line), _HLO_TYPED.match(line)
+        if not (op and d):
+            continue
+        args = [shapes.get(a.strip().lstrip("%"), ("?", 0))
+                for a in op.group(2).split(",")]
+        if args[1][1] % L == 0:               # the indices: L lanes (x k)
+            found.append((op.group(1), (d.group(2), _elements(d.group(3))),
+                          args))
+    return found
+
+
+def test_churn_tick_has_no_per_tenant_scatter_or_gather(churn_tick_hlo):
+    """On the TPU the churn tick's integer per-tenant sums and its lookups
+    from (T+1)-entry tables are compare-and-reduce, not a scatter-add or a
+    gather over the page axis (either runs near-serially over its L
+    indices on the chip). The page-axis scatters and gathers left are
+    pinned by count, so the census can only fall."""
+    ops = _page_axis_ops(churn_tick_hlo)
+    per_tenant = [
+        o for o in ops
+        if (o[0] == "scatter" and o[1][1] == T + 1 and o[2][2][0] != "f32")
+        or (o[0] == "gather" and o[2][0][1] == T + 1)]
+    assert not per_tenant, per_tenant
+    # left: 2 f32 perf-model sums, 6 inverse permutations of the segment
+    # ranks, 3 residency histograms + 2 thrash-table writes, 3 migration
+    # ring appends; 4 thrash-table reads, the rates[owner, rank] gather
+    kinds = [o[0] for o in ops]
+    assert (kinds.count("scatter"), kinds.count("gather")) == (16, 5), ops
