@@ -1,9 +1,10 @@
 #!/usr/bin/env bash
 # CI entry point: tier-1 test suite (default marks: slow excluded, ~3 min)
-# plus a fast fleet-observability smoke (clean fleet silent, injected noisy
-# neighbor flagged — the obs/ acceptance property).
+# plus the static analysis gate and the obs/engine/fleet smokes. The obs/
+# acceptance property (a clean fleet is silent, an injected noisy neighbor
+# is flagged) is pinned by the fleet tests of the tier-1 suite.
 #
-#   scripts/check.sh          # default suite + obs smoke
+#   scripts/check.sh          # default suite + smokes
 #   scripts/check.sh --full   # include slow-marked tests (full matrix)
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -19,9 +20,6 @@ python -m pytest -x -q "${MARK[@]}"
 
 echo "== static analysis gate (jaxpr passes + repo lint vs committed baseline) =="
 python -m repro.analysis --gate
-
-echo "== obs fleet smoke (4 hosts) =="
-python -m benchmarks.fleet_obs --smoke
 
 echo "== obs exporter smoke (Chrome trace + Prometheus exposition) =="
 python -m benchmarks.obs_export --smoke

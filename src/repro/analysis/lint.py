@@ -78,7 +78,7 @@ class _Linter(ast.NodeVisitor):
     def _check_seam_defaults(self, node: ast.FunctionDef):
         # The seam contract binds *builders* (make_tick, make_churn_tick,
         # ...): their seams must default to None so engines compose
-        # without observability subtrees. Plain runner flags (run_fleet's
+        # without observability subtrees. Plain runner flags (fleet_rollout's
         # detect=True toggle) are API surface, not graph seams.
         if not _is_builder(node.name):
             return

@@ -1,41 +1,33 @@
-"""Fleet telemetry harness: the unified tick (core/tick.py) stacked across
-N simulated hosts.
+"""Fleet harness: the unified churn tick (core/tick.py) vmapped across H
+simulated hosts and advanced over long horizons by ``FleetRollout``
+(``fleet_rollout`` is its one-call form).
 
-This is the ROADMAP's fleet-scale evaluation vehicle, rebuilt on the
-unified tick core so a fleet is a batch of *heterogeneous* hosts — static
-rosters and churned rosters side by side under ONE ``vmap`` (every host
-runs the dynamic-ownership provider; a static host is simply the
-degenerate schedule with constant ``want``). Three execution surfaces:
+Every host runs the dynamic-ownership provider; a static roster is the
+degenerate schedule with constant ``want`` (``mixed_fleet_hosts``), so
+static and churned hosts sit side by side under ONE ``vmap`` — the host
+mix is schedule data, not program structure. Schedules enter as
+*archetypes* (``stack_schedules``: want [A, P, T], rates [A, P, T, S])
+tiled periodically in time and gathered per host in-graph, so H hosts
+over a 10k-tick horizon cost O(A * P) schedule memory.
 
-  ``run_fleet``        — the original static-layout fleet (hosts share one
-                         owner vector; heterogeneity from workload data).
-                         Kept for the obs acceptance property and as the
-                         cheapest path when no host churns.
-  ``run_mixed_fleet``  — heterogeneous static+churn hosts under one vmap,
-                         full per-tick telemetry + pathology detection.
-  ``FleetRollout``     — the long-horizon engine, built once and advanced
-                         in place (``fleet_rollout`` runs one horizon
-                         through it): chunked ``lax.scan``
-                         rollouts with donated carries (no host round-trips
-                         inside a chunk, O(chunk) not O(horizon) output
-                         memory), schedule archetypes gathered in-graph
-                         (hosts sharing a schedule cost one copy), tiled
-                         periodically so a 10k-tick horizon streams through
-                         a fixed-size schedule, and sharded across devices
-                         via ``pmap`` when more than one is available.
+The fleet advances in chunked ``lax.scan``s (``make_fleet_chunk``) with
+the fleet state donated between chunks and per-tick outputs reduced to
+[H] running sums inside the scan: no host round-trips inside a chunk and
+O(chunk), not O(horizon), output memory. At each chunk boundary the
+in-graph int32 cumulative counters are widened into the int64
+``CounterLedger``, exact at any horizon. With more than one local device
+the chunks run under ``pmap`` with hosts sharded across the devices.
 
-In-graph obs state (TierStats + migration ring) is collected per host with
-zero extra tracing work — ``vmap`` batches the scatter/adds along the host
-axis. Host-side, telemetry is decoded per host and rolled up fleet-wide:
-latency percentiles, migration rates, pathology counts from
-``obs.pathology``.
+In-graph telemetry (TierStats, migration ring, the streaming pathology
+detectors and the attribution ledger) rides in the fleet state;
+``RolloutSummary`` decodes it per host and rolls it up fleet-wide.
 """
 from __future__ import annotations
 
 import copy
 import time
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,225 +36,19 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import TieringConfig
 from repro.core.churn import ChurnSchedule, make_churn_tick
-from repro.core.engine import make_tick
-from repro.core.simulator import tenant_activity
 from repro.core.state import init_state, stack_states
-from repro.core.workloads import (ChurnSlot, TenantWorkload, as_churn_slots,
-                                  build_churn_schedule, build_trace,
-                                  cache_like, ci_like, microbenchmark,
-                                  spark_like, thrasher, web_like)
+from repro.core.workloads import ChurnSlot, TenantWorkload, as_churn_slots
 from repro.obs.attribution import (COMPONENTS, AttributionSpec,
-                                   attribution_conserved, fast_hit_fraction,
-                                   make_attribution)
-from repro.obs.pathology import Pathology, count_by_kind, detect_all
+                                   fast_hit_fraction, make_attribution)
+from repro.obs.pathology import Pathology, count_by_kind
 from repro.obs.sketch import sketch_merge, sketch_percentiles
 from repro.obs.stats import stats_summary
-from repro.obs.streaming import (KINDS, DetectorSpec, make_detector,
+from repro.obs.streaming import (DetectorSpec, make_detector,
                                  streaming_pathologies)
 from repro.obs.trace import decode_ring
 
-# stable-pattern menu for clean hosts (hot sets that mostly fit fast tier)
-MIX_MENU = ("web", "cache", "micro", "ci", "spark")
 
-
-def heterogeneous_mixes(footprints: Sequence[int], n_hosts: int,
-                        seed: int = 0, menu: Sequence[str] = MIX_MENU,
-                        stagger: int = 8) -> List[List[TenantWorkload]]:
-    """One tenant mix per host. Footprints are fixed per tenant *slot* (every
-    host shares the static page-ownership layout ``run_fleet`` needs); the
-    workload pattern and arrival of each slot vary per host."""
-    rng = np.random.default_rng(seed)
-    mk = {
-        "web": lambda f, a: web_like(f, arrival=a),
-        "cache": lambda f, a: cache_like(f, arrival=a),
-        "micro": lambda f, a: microbenchmark(f, arrival=a),
-        "ci": lambda f, a: ci_like(f, arrival=a),
-        "spark": lambda f, a: spark_like(f, arrival=a),
-    }
-    mixes = []
-    for _ in range(n_hosts):
-        mix = []
-        for f in footprints:
-            kind = menu[int(rng.integers(len(menu)))]
-            arrival = int(rng.integers(0, stagger + 1))
-            mix.append(mk[kind](f, arrival))
-        mixes.append(mix)
-    return mixes
-
-
-def inject_noisy_neighbor(mixes: List[List[TenantWorkload]], tenant: int,
-                          fast_share: int,
-                          hosts: Optional[Sequence[int]] = None,
-                          arrival: Optional[int] = None
-                          ) -> List[List[TenantWorkload]]:
-    """Replace ``tenant``'s workload with a thrasher (promotion-hot pages
-    never re-accessed before demotion — the §V-B5 noisy neighbor) on the
-    given hosts (default: all). Footprint is preserved so the fleet keeps a
-    common ownership layout. A late ``arrival`` gives detectors a clean
-    baseline window before the noise starts."""
-    hosts = set(range(len(mixes))) if hosts is None else set(hosts)
-    out = []
-    for h, mix in enumerate(mixes):
-        mix = list(mix)
-        if h in hosts:
-            a = mix[tenant].arrival if arrival is None else arrival
-            mix[tenant] = thrasher(mix[tenant].footprint, fast_share,
-                                   arrival=a)
-        out.append(mix)
-    return out
-
-
-@dataclass
-class FleetResult:
-    mode: str
-    n_hosts: int
-    # [H, ticks, T] each
-    fast_usage: np.ndarray
-    slow_usage: np.ndarray
-    promotions: np.ndarray
-    demotions: np.ndarray
-    throughput: np.ndarray
-    latency: np.ndarray
-    thrash_events: np.ndarray
-    attempted: np.ndarray
-    lower_protection: tuple
-    # per-host decoded telemetry
-    stats: List[dict] = field(default_factory=list)   # stats_summary per host
-    pathologies: List[List[Pathology]] = field(default_factory=list)
-    # [H, ticks, T] bool per-host tenant roster (tenant has live pages);
-    # detectors and roll-ups use it to tolerate mid-window departures
-    active: Optional[np.ndarray] = None
-    _final_state: object = None
-
-    def steady_window(self, frac: float = 0.5) -> slice:
-        n = self.latency.shape[1]
-        return slice(int(n * (1 - frac)), n)
-
-    def host_migrations(self, host: int):
-        """Decode one host's migration ring -> (events, n_dropped)."""
-        ring = jax.tree_util.tree_map(lambda x: x[host],
-                                      self._final_state.ring)
-        return decode_ring(ring)
-
-    def pathology_counts(self) -> Dict[str, int]:
-        """Fleet-wide counts by kind, keys sorted (stable across runs)."""
-        out: Dict[str, int] = {}
-        for ps in self.pathologies:
-            for k, v in count_by_kind(ps).items():
-                out[k] = out.get(k, 0) + v
-        return dict(sorted(out.items()))
-
-    def tenants_flagged(self, kind: Optional[str] = None
-                        ) -> List[Tuple[int, int]]:
-        """Sorted unique (host, tenant) pairs flagged, optionally for one
-        pathology kind — deterministic order, safe for golden tests."""
-        out = set()
-        for h, ps in enumerate(self.pathologies):
-            for p in ps:
-                if kind is None or p.kind == kind:
-                    out.add((h, p.tenant))
-        return sorted(out)
-
-    def rollup(self) -> dict:
-        """Fleet-wide operator summary. Latency/throughput aggregates cover
-        only resident tenant-ticks (``active``) so hosts with mid-window
-        departures don't dilute percentiles with the idle-slot constant."""
-        w = self.steady_window()
-        lat = self.latency[:, w]
-        mig = self.promotions[:, w] + self.demotions[:, w]
-        hosts_bad = sum(1 for ps in self.pathologies if ps)
-        if self.active is not None:
-            act = np.asarray(self.active[:, w], bool)
-            act = act if act.any() else np.ones_like(act)
-            lat_vals = lat[act]
-            thru_vals = self.throughput[:, w][act]
-            worst_host = max(
-                float(np.percentile(lat[h][act[h]], 99))
-                for h in range(self.n_hosts) if act[h].any())
-        else:
-            lat_vals, thru_vals = lat, self.throughput[:, w]
-            worst_host = float(np.percentile(lat, 99, axis=(1, 2)).max())
-        return {
-            "hosts": self.n_hosts,
-            "ticks": self.latency.shape[1],
-            "tenants": self.latency.shape[2],
-            "latency_p50": float(np.percentile(lat_vals, 50)),
-            "latency_p99": float(np.percentile(lat_vals, 99)),
-            "latency_worst_host_p99": worst_host,
-            "throughput_mean": float(thru_vals.mean()),
-            "migrations_per_tick": float(mig.sum(axis=2).mean()),
-            "thrash_total": int(self.thrash_events[:, -1].sum()),
-            "pathology_counts": self.pathology_counts(),
-            "hosts_with_pathology": hosts_bad,
-        }
-
-
-def _fleet_result(mode: str, cfg: TieringConfig, finals, outs,
-                  active: np.ndarray, detect: bool) -> FleetResult:
-    """One FleetResult builder shared by the static and mixed fleets."""
-    H = active.shape[0]
-    res = FleetResult(
-        mode=mode, n_hosts=H,
-        fast_usage=np.asarray(outs.fast_usage),
-        slow_usage=np.asarray(outs.slow_usage),
-        promotions=np.asarray(outs.promotions),
-        demotions=np.asarray(outs.demotions),
-        throughput=np.asarray(outs.throughput),
-        latency=np.asarray(outs.latency),
-        thrash_events=np.asarray(outs.thrash_events),
-        attempted=np.asarray(outs.attempted_promotions),
-        lower_protection=tuple(cfg.lower_protection[:cfg.n_tenants]),
-        active=active,
-        _final_state=finals)
-    res.stats = [stats_summary(jax.tree_util.tree_map(lambda x: x[h],
-                                                      finals.stats))
-                 for h in range(H)]
-    if detect:
-        res.pathologies = [
-            detect_all(res.fast_usage[h], res.slow_usage[h],
-                       res.promotions[h], res.demotions[h], res.latency[h],
-                       res.thrash_events[h], attempted=res.attempted[h],
-                       lower_protection=res.lower_protection,
-                       active=res.active[h])
-            for h in range(H)]
-    return res
-
-
-def run_fleet(cfg: TieringConfig, host_mixes: List[List[TenantWorkload]],
-              ticks: int, mode: str = "equilibria", k_max: int = 64,
-              detect: bool = True) -> FleetResult:
-    """Run every host's trace through one vmapped static-provider tick.
-
-    All hosts must share the tenant footprint layout (same owner vector);
-    ``heterogeneous_mixes`` guarantees that by construction. For fleets
-    mixing static and churned hosts, use ``run_mixed_fleet``.
-    """
-    traces = [build_trace(mix, ticks) for mix in host_mixes]
-    owner = traces[0][0]
-    for o, _, _ in traces[1:]:
-        if not np.array_equal(o, owner):
-            raise ValueError("all hosts must share the footprint layout "
-                             "(same per-tenant page counts)")
-    cfg = cfg.with_(n_tenants=len(host_mixes[0]))
-    H = len(host_mixes)
-    accesses = jnp.asarray(np.stack([t[1] for t in traces]), jnp.float32)
-    alive = jnp.asarray(np.stack([t[2] for t in traces]), bool)
-
-    tick = make_tick(cfg, owner, mode, k_max)
-    states = stack_states(init_state(cfg, owner.shape[0], owner=owner), H)
-
-    @jax.jit
-    @jax.vmap
-    def run_host(state, acc, alv):
-        return jax.lax.scan(tick, state, (acc, alv))
-
-    finals, outs = run_host(states, accesses, alive)
-    active = np.stack([tenant_activity(owner, np.asarray(tr[2]),
-                                       cfg.n_tenants) for tr in traces])
-    return _fleet_result(mode, cfg, finals, outs, active, detect)
-
-
-# --------------------------------------------------------- mixed fleets ----
+# ------------------------------------------------------------ schedules ----
 def stack_schedules(schedules: List[ChurnSchedule]
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """Stack per-host churn schedules into fleet arrays, padding every
@@ -293,38 +79,6 @@ def mixed_fleet_hosts(static_mixes: List[List[TenantWorkload]],
     become single-episode slots (the degenerate schedule)."""
     return [as_churn_slots(mix, ticks) for mix in static_mixes] + \
         [list(slots) for slots in churn_hosts]
-
-
-def run_mixed_fleet(cfg: TieringConfig, hosts: List[List[ChurnSlot]],
-                    ticks: int, mode: str = "equilibria", k_max: int = 64,
-                    detect: bool = True,
-                    n_pages: Optional[int] = None) -> FleetResult:
-    """Heterogeneous fleet: static and churned hosts side by side under one
-    vmap of the unified dynamic-ownership tick. ``hosts`` is one churn-slot
-    roster per host (``mixed_fleet_hosts`` builds it from static mixes +
-    churn rosters); every host needs the same slot count, nothing else.
-    """
-    T = len(hosts[0])
-    for slots in hosts[1:]:
-        if len(slots) != T:
-            raise ValueError("all hosts must have the same slot count")
-    cfg = cfg.with_(n_tenants=T)
-    want, rates = stack_schedules(
-        [build_churn_schedule(slots, ticks) for slots in hosts])
-    H = want.shape[0]
-    L = n_pages if n_pages is not None else \
-        cfg.n_fast_pages + cfg.n_slow_pages
-    tick = make_churn_tick(cfg, L, mode=mode, k_max=k_max)
-    states = stack_states(init_state(cfg, L), H)
-
-    @jax.jit
-    @jax.vmap
-    def run_host(state, r, w):
-        return jax.lax.scan(tick, state, (r, w))
-
-    finals, outs = run_host(states, jnp.asarray(rates, jnp.float32),
-                            jnp.asarray(want, jnp.int32))
-    return _fleet_result(mode, cfg, finals, outs, want > 0, detect)
 
 
 # ----------------------------------------------- long-horizon rollouts ----
@@ -420,12 +174,12 @@ class RolloutSummary:
     latency_mean: np.ndarray         # [H] mean per-tick tenant-mean latency
     throughput_mean: np.ndarray      # [H] mean per-tick total throughput
     migrations_per_tick: np.ndarray  # [H]
+    # host-side int64 widening of the in-graph int32 cumulative counters
+    # ({"counters": Counters, "att": {...}}), exact at any horizon
+    ledger: CounterLedger
     final_state: object = None       # batched TierState [H, ...]
     detector: Optional[DetectorSpec] = None
     attribution: Optional[AttributionSpec] = None
-    # host-side int64 widening of the in-graph int32 cumulative counters
-    # ({"counters": Counters, "att": {...}}), exact at any horizon
-    ledger: Optional[CounterLedger] = None
 
     @property
     def host_ticks_per_s(self) -> float:
@@ -436,12 +190,9 @@ class RolloutSummary:
             lambda x: x[host], self.final_state.stats))
 
     def counters(self):
-        """Cumulative per-tenant counters [H, T]. With the chunk-boundary
-        ledger (the default rollout path) these are int64 and exact even
-        where the in-graph int32 carry wrapped."""
-        if self.ledger is not None:
-            return self.ledger.total["counters"]
-        return jax.tree_util.tree_map(np.asarray, self.final_state.counters)
+        """Cumulative per-tenant counters [H, T], int64 from the ledger:
+        exact even where the in-graph int32 carry wrapped."""
+        return self.ledger.total["counters"]
 
     def host_migrations(self, host: int):
         """Decode one host's migration ring -> (events, n_dropped)."""
@@ -489,27 +240,19 @@ class RolloutSummary:
             raise ValueError("rollout ran with attrib=False")
         return self.final_state.attrib
 
-    def _att_ledger(self) -> Optional[dict]:
-        if self.ledger is not None and "att" in self.ledger.total:
-            if self.attribution is None:
-                raise ValueError("rollout ran with attrib=False")
-            return self.ledger.total["att"]
-        return None
+    def _att_ledger(self) -> dict:
+        if self.attribution is None:
+            raise ValueError("rollout ran with attrib=False")
+        return self.ledger.total["att"]
 
     def attribution_components(self) -> np.ndarray:
         """[H, T, len(COMPONENTS)] int64 cumulative stall units by cause
-        (ledger-widened: exact past int32 wrap on the default path)."""
-        led = self._att_ledger()
-        if led is not None:
-            return led["comp"]
-        return np.asarray(self._att().comp, np.int64)
+        (ledger-widened: exact past the int32 wrap)."""
+        return self._att_ledger()["comp"]
 
     def attribution_totals(self) -> np.ndarray:
         """[H, T] int64 cumulative stall units (== components summed)."""
-        led = self._att_ledger()
-        if led is not None:
-            return led["total"]
-        return np.asarray(self._att().total, np.int64)
+        return self._att_ledger()["total"]
 
     def fast_hit_fraction(self) -> np.ndarray:
         """[H, T] fraction of access mass served from the fast tier."""
@@ -517,10 +260,7 @@ class RolloutSummary:
 
     def stall_sketch(self) -> np.ndarray:
         """Fleet-merged per-tick stall-unit histogram ([SKETCH_BUCKETS])."""
-        led = self._att_ledger()
-        if led is not None:
-            return sketch_merge(led["sketch"])
-        return sketch_merge(self._att().sketch)
+        return sketch_merge(self._att_ledger()["sketch"])
 
     def stall_percentiles(self, qs=(0.5, 0.95, 0.99)) -> np.ndarray:
         """Fleet-wide per-tick total-stall percentiles from the merged
@@ -529,20 +269,18 @@ class RolloutSummary:
 
     def attribution_conserved(self) -> bool:
         """Every host's ledger conserves: components sum to the total and
-        the total matches the counter identity, bit-exact. On the default
-        path the identity is checked on the int64-widened values, so it
-        holds even past the in-graph int32 wrap point."""
+        the total matches the counter identity, bit-exact. The identity is
+        checked on the int64-widened values, so it holds even past the
+        in-graph int32 wrap point."""
         led = self._att_ledger()
-        if led is not None:
-            c = self.counters()
-            comp, total = led["comp"], led["total"]
-            expect = (np.asarray(c.attempted_promotions, np.int64)
-                      - np.asarray(c.promotions, np.int64)
-                      + np.asarray(c.reclaims, np.int64))
-            return bool((comp.sum(axis=-1) == total).all()
-                        and (comp >= 0).all()
-                        and (total == expect).all())
-        return attribution_conserved(self._att(), self.final_state.counters)
+        c = self.counters()
+        comp, total = led["comp"], led["total"]
+        expect = (np.asarray(c.attempted_promotions, np.int64)
+                  - np.asarray(c.promotions, np.int64)
+                  + np.asarray(c.reclaims, np.int64))
+        return bool((comp.sum(axis=-1) == total).all()
+                    and (comp >= 0).all()
+                    and (total == expect).all())
 
     def attribution_rollup(self) -> dict:
         """Operator roll-up: fleet component shares, worst tenants, sketch
@@ -570,8 +308,8 @@ class RolloutSummary:
         }
 
     def pathology_rollup(self) -> dict:
-        """Operator roll-up of the streamed pathology state (the fleet-scale
-        analogue of ``FleetResult.rollup``, O(H * T) not O(H * ticks))."""
+        """Operator roll-up of the streamed pathology state, O(H * T) host
+        memory at any horizon."""
         flagged = self.tenants_flagged()
         first = self.pathology_first_flag()
         return {

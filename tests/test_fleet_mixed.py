@@ -24,7 +24,7 @@ from repro.core.state import init_state, stack_states
 from repro.core.workloads import (ChurnSlot, build_churn_schedule,
                                   cache_like, spark_like, thrasher, web_like)
 from repro.obs.fleet import (FleetRollout, fleet_rollout, mixed_fleet_hosts,
-                             run_mixed_fleet, stack_schedules)
+                             stack_schedules)
 
 _TICKS = 160
 # slot footprints shared fleet-wide (slot count must match across hosts;
@@ -71,28 +71,36 @@ def _hosts(noisy_host=None):
     return hosts
 
 
+def _rollout(hosts):
+    want, rates = stack_schedules(
+        [build_churn_schedule(s, _TICKS) for s in hosts])
+    return want, fleet_rollout(_cfg(), want, rates, _TICKS, chunk=80,
+                               k_max=32)
+
+
 def test_mixed_fleet_clean_is_silent():
-    res = run_mixed_fleet(_cfg(), _hosts(), _TICKS, k_max=32)
-    assert res.n_hosts == 4
-    assert res.latency.shape == (4, _TICKS, 4)
+    want, res = _rollout(_hosts())
+    assert res.n_hosts == 4 and res.ticks == _TICKS
+    assert res.latency_mean.shape == (4,)
     assert res.tenants_flagged() == [], res.pathology_counts()
     # the churned hosts really churned: slot 2 left and came back
-    assert not res.active[2, 70, 2] and res.active[2, 100, 2]
-    roll = res.rollup()
+    assert want[2, 70, 2] == 0 and want[2, 100, 2] > 0
+    roll = res.pathology_rollup()
     assert roll["hosts_with_pathology"] == 0
-    assert roll["latency_p99"] >= roll["latency_p50"] >= 1.0
+    assert roll["pathology_counts"] == {}
+    assert roll["earliest_flag_tick"] == -1
+    assert (res.latency_mean >= 1.0).all()
 
 
 def test_noisy_neighbor_on_churned_host_is_flagged():
     noisy_host = 2                      # a churned host
-    res = run_mixed_fleet(_cfg(), _hosts(noisy_host=noisy_host), _TICKS,
-                          k_max=32)
+    _, res = _rollout(_hosts(noisy_host=noisy_host))
     flagged = res.tenants_flagged("chronic_thrashing")
     assert (noisy_host, 0) in flagged, res.pathology_counts()
     # the injection is host-local: nobody else in the fleet is flagged
     assert {h for h, _ in res.tenants_flagged()} == {noisy_host}
     # per-host in-graph stats saw the churn too
-    assert res.stats[noisy_host]["thrash_rate"][0] > 0
+    assert res.host_stats(noisy_host)["thrash_rate"][0] > 0
 
 
 def test_fleet_jaxpr_constant_in_host_mix():

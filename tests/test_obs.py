@@ -218,34 +218,56 @@ def test_demo_success_ratio_bounded_with_sync_demotions():
 
 # ------------------------------------------------------ fleet under vmap ----
 def test_fleet_rollup_shapes_and_detection():
-    from repro.obs.fleet import (heterogeneous_mixes, inject_noisy_neighbor,
-                                 run_fleet)
+    from repro.core.workloads import (as_churn_slots, build_churn_schedule,
+                                      cache_like, ci_like, microbenchmark,
+                                      spark_like, thrasher, web_like)
+    from repro.obs.fleet import fleet_rollout, stack_schedules
+    from repro.obs.streaming import KINDS
     H, T, ticks = 4, 3, 120
     cfg = TieringConfig(n_tenants=T, n_fast_pages=256, n_slow_pages=256,
                         lower_protection=(64, 64, 64), upper_bound=(0, 0, 0),
                         migration_cost=0.005, obs_ring_capacity=128)
-    mixes = heterogeneous_mixes([80, 80, 64], n_hosts=H, seed=1)
-    res = run_fleet(cfg, mixes, ticks, k_max=32)
-    for arr in (res.latency, res.throughput, res.fast_usage, res.promotions,
-                res.attempted, res.thrash_events):
-        assert arr.shape == (H, ticks, T)
-    assert len(res.stats) == H
-    assert all(s["resid_hist"].shape == (T, cfg.obs_resid_buckets)
-               for s in res.stats)
-    roll = res.rollup()
-    assert roll["hosts"] == H and roll["tenants"] == T
-    assert roll["latency_p99"] >= roll["latency_p50"] >= 1.0
+    # static rosters sharing per-slot footprints; pattern and arrival vary
+    # per host
+    mixes = [
+        [microbenchmark(80, arrival=4), ci_like(80, arrival=8),
+         web_like(64, arrival=1)],
+        [spark_like(80, arrival=8), cache_like(80, arrival=2),
+         spark_like(64, arrival=3)],
+        [cache_like(80, arrival=7), cache_like(80, arrival=3),
+         ci_like(64, arrival=4)],
+        [web_like(80, arrival=0), spark_like(80, arrival=6),
+         spark_like(64, arrival=4)],
+    ]
+
+    def rollout(cfg, mixes):
+        want, rates = stack_schedules(
+            [build_churn_schedule(as_churn_slots(m, ticks), ticks)
+             for m in mixes])
+        return fleet_rollout(cfg, want, rates, ticks, chunk=60, k_max=32)
+
+    res = rollout(cfg, mixes)
+    assert res.n_hosts == H and res.ticks == ticks
+    for arr in (res.latency_mean, res.throughput_mean,
+                res.migrations_per_tick):
+        assert arr.shape == (H,)
+    assert (res.latency_mean >= 1.0).all()
+    for h in range(H):
+        assert res.host_stats(h)["resid_hist"].shape == \
+            (T, cfg.obs_resid_buckets)
+    assert res.pathology_flag_ticks().shape == (H, T, len(KINDS))
     # per-host ring decodes independently
     ev, _ = res.host_migrations(0)
     assert ev.dtype == OT.EVENT_DTYPE
     # an injected noisy neighbor is flagged; this clean fleet is not
     assert res.tenants_flagged() == []
-    noisy = run_fleet(
-        cfg.with_(upper_bound=(12, 0, 0)),
-        inject_noisy_neighbor(mixes, tenant=0, fast_share=12, arrival=40),
-        ticks, k_max=32)
-    flagged = noisy.tenants_flagged("chronic_thrashing")
-    assert flagged and all(t == 0 for _, t in flagged)
+    noisy_mixes = [[thrasher(80, fast_share=12, arrival=40)] + m[1:]
+                   for m in mixes]
+    noisy = rollout(cfg.with_(upper_bound=(12, 0, 0)), noisy_mixes)
+    # on every host, and only the injected tenant
+    assert noisy.tenants_flagged("chronic_thrashing") == \
+        [(h, 0) for h in range(H)]
+    assert all(t == 0 for _, t in noisy.tenants_flagged())
 
 
 # --------------------------------------------------- serving-path stats ----

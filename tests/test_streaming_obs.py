@@ -24,6 +24,7 @@ import pytest
 
 from repro.configs.base import TieringConfig
 from repro.core.churn import make_churn_tick, run_churn_engine
+from repro.core.simulator import simulate_churn
 from repro.core.state import init_state
 from repro.core.workloads import (ChurnSlot, build_churn_schedule,
                                   cache_like, spark_like, thrasher, web_like)
@@ -31,8 +32,7 @@ from repro.obs import pathology as PA
 from repro.obs.export import (chrome_trace, fleet_exposition,
                               rollout_exposition, validate_chrome_trace,
                               validate_exposition)
-from repro.obs.fleet import (fleet_rollout, mixed_fleet_hosts,
-                             run_mixed_fleet, stack_schedules)
+from repro.obs.fleet import fleet_rollout, mixed_fleet_hosts, stack_schedules
 from repro.obs.pathology import detect_all, detect_chronic_thrashing
 from repro.obs.stats import bucket_edges, hist_percentile, hist_percentile_j
 from repro.obs.streaming import (KINDS, make_detector, run_detector,
@@ -92,6 +92,19 @@ def _assert_agree(online, offline):
             assert p.evidence[k] == pytest.approx(v, rel=5e-2, abs=1e-6)
 
 
+def _offline_per_host(hosts):
+    """The offline side of the fleet differential: each host alone through
+    the single-host churn engine, full traces, offline detectors."""
+    return [simulate_churn(_cfg(), slots, _TICKS, k_max=32).pathologies()
+            for slots in hosts]
+
+
+def _flagged(per_host):
+    """Sorted unique (host, tenant) pairs over per-host pathology lists."""
+    return sorted({(h, p.tenant) for h, ps in enumerate(per_host)
+                   for p in ps})
+
+
 def _offline_from_run(cfg, outs, active):
     return detect_all(
         np.asarray(outs.fast_usage), np.asarray(outs.slow_usage),
@@ -142,11 +155,12 @@ def test_differential_churned_thrasher_single_host():
 
 
 def test_differential_clean_fleet_silent():
-    """Scenario: clean mixed fleet. Offline (run_mixed_fleet, full traces)
-    and streaming (fleet_rollout, O(1) trace memory) both stay silent."""
+    """Scenario: clean mixed fleet. Offline (each host through
+    simulate_churn, full traces) and streaming (fleet_rollout, O(1) trace
+    memory) both stay silent."""
     hosts = _hosts()
-    offline = run_mixed_fleet(_cfg(), hosts, _TICKS, k_max=32)
-    assert offline.tenants_flagged() == []
+    offline = _offline_per_host(hosts)
+    assert _flagged(offline) == []
 
     want, rates = stack_schedules(
         [build_churn_schedule(s, _TICKS) for s in hosts])
@@ -154,6 +168,8 @@ def test_differential_clean_fleet_silent():
     assert roll.tenants_flagged() == []
     assert roll.pathology_counts() == {}
     assert roll.pathology_rollup()["hosts_with_pathology"] == 0
+    for h in range(roll.n_hosts):
+        _assert_agree(roll.host_pathologies(h), offline[h])
 
 
 def test_differential_noisy_fleet_flagged():
@@ -161,16 +177,17 @@ def test_differential_noisy_fleet_flagged():
     exactly (host 2, tenant 0) and agree on the per-host verdicts."""
     noisy = 2
     hosts = _hosts(noisy_host=noisy)
-    offline = run_mixed_fleet(_cfg(), hosts, _TICKS, k_max=32)
+    offline = _offline_per_host(hosts)
     want, rates = stack_schedules(
         [build_churn_schedule(s, _TICKS) for s in hosts])
     roll = fleet_rollout(_cfg(), want, rates, _TICKS, chunk=64, k_max=32)
 
     assert (noisy, 0) in roll.tenants_flagged("chronic_thrashing")
-    assert roll.tenants_flagged() == offline.tenants_flagged()
-    assert roll.pathology_counts() == offline.pathology_counts()
+    assert roll.tenants_flagged() == _flagged(offline)
+    assert roll.pathology_counts() == \
+        PA.count_by_kind([p for ps in offline for p in ps])
     for h in range(roll.n_hosts):
-        _assert_agree(roll.host_pathologies(h), offline.pathologies[h])
+        _assert_agree(roll.host_pathologies(h), offline[h])
     # online-only signals: the flag was raised while the run was live
     k = KINDS.index("chronic_thrashing")
     first = roll.pathology_first_flag()
@@ -178,7 +195,7 @@ def test_differential_noisy_fleet_flagged():
     assert roll.pathology_flag_ticks()[noisy, 0, k] > 0
     # deterministic ordering (satellite: sorted, list not set)
     assert roll.tenants_flagged() == sorted(roll.tenants_flagged())
-    assert isinstance(offline.tenants_flagged(), list)
+    assert isinstance(roll.tenants_flagged(), list)
 
 
 def test_detector_jaxpr_constant_in_horizon():
